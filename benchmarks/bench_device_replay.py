@@ -24,9 +24,10 @@ from __future__ import annotations
 import time
 
 from benchmarks.common import Row
-from benchmarks.bench_replay import DEFAULT_REQUESTS, FULL_REQUESTS, _make_trace
-from repro.core import FleetSimulator
+from benchmarks.bench_replay import DEFAULT_REQUESTS, FULL_REQUESTS
+from repro.core import FleetProgram, FleetSimulator
 from repro.core.workloads import GiB, MiB
+from repro.testing.traces import replay_trace
 
 NODES = 64
 SCHEMES = ("orangefs", "orangefs-bb", "ssdup", "ssdup+")
@@ -34,16 +35,9 @@ POLICY = "range-offset"
 
 
 def run(total_bytes: int = 2 * GiB) -> list[Row]:
-    try:
-        import jax  # noqa: F401
-    except Exception:
-        print("jax unavailable; skipping device replay benchmark")
-        return []
-    from repro.core import FleetProgram
-
     rows: list[Row] = []
     n = FULL_REQUESTS if total_bytes >= 16 * GiB else DEFAULT_REQUESTS
-    batch = _make_trace(n)
+    batch = replay_trace(n)
     cap = max(batch.total_bytes // 2 // NODES, 64 * MiB)
     lanes = NODES * len(SCHEMES)
 

@@ -17,6 +17,7 @@ from __future__ import annotations
 
 import time
 
+import jax
 import numpy as np
 
 from benchmarks.common import Row, timeit
@@ -64,23 +65,21 @@ def bench_scoring(rows: list[Row]) -> None:
     rows.append(Row("fleet_score_scalar", t_scalar * 1e6,
                     f"streams_per_s={sps:.0f}"))
 
-    backends = ["numpy"]
-    try:
-        import jax  # noqa: F401
-        backends += ["jnp", "pallas"]
-    except Exception:
-        pass
-    for backend in backends:
-        compute_stream_scores(batch, STREAM_LEN, backend=backend)  # warmup
-        us, _ = timeit(
-            lambda: compute_stream_scores(batch, STREAM_LEN, backend=backend),
-            repeat=3,
-        )
+    # off the TPU the kernel runs in the Pallas interpreter, and its row
+    # says so (fleet_score_pallas-interpret)
+    interpret = jax.default_backend() != "tpu"
+    for backend in ("numpy", "jnp", "pallas"):
+        def score():
+            return compute_stream_scores(batch, STREAM_LEN, backend=backend,
+                                         interpret=interpret)
+
+        ran = score().backend  # warmup
+        us, _ = timeit(score, repeat=3)
         t = us / 1e6
         speedup = t_scalar / t
-        print(f"{'batched-' + backend:18s} {t*1e3:9.1f} ms   "
+        print(f"{'batched-' + ran:18s} {t*1e3:9.1f} ms   "
               f"{SCORE_STREAMS/t:12.0f} streams/s   {speedup:5.1f}x vs scalar")
-        rows.append(Row(f"fleet_score_{backend}", us,
+        rows.append(Row(f"fleet_score_{ran}", us,
                         f"speedup_vs_scalar={speedup:.1f}"))
 
 

@@ -19,39 +19,21 @@ from __future__ import annotations
 import dataclasses
 import time
 
-import numpy as np
-
 from benchmarks.common import Row
 from repro.core import (
     FleetSimulator,
     IONodeSimulator,
-    TraceBatch,
     compute_stream_scores,
 )
 from repro.core.workloads import GiB, MiB
+from repro.testing.traces import replay_trace
 
-REQ_SIZE = 64 << 10
 DEFAULT_REQUESTS = 1_000_000
 FULL_REQUESTS = 4_000_000
 
 
-def _make_trace(n_requests: int, seed: int = 0) -> TraceBatch:
-    """Random-heavy multi-app trace with a mid-trace compute gap."""
-
-    rng = np.random.default_rng(seed)
-    return TraceBatch(
-        offsets=rng.integers(0, 1 << 38, size=n_requests).astype(np.int64),
-        sizes=np.full(n_requests, REQ_SIZE, dtype=np.int64),
-        file_ids=rng.integers(0, 16, size=n_requests).astype(np.int64),
-        app_ids=rng.integers(0, 8, size=n_requests).astype(np.int64),
-        times=np.zeros(n_requests),
-        gap_positions=np.asarray([n_requests // 2], dtype=np.int64),
-        gap_seconds=np.asarray([30.0]),
-    )
-
-
 def bench_replay_speedup(rows: list[Row], n_requests: int) -> None:
-    batch = _make_trace(n_requests)
+    batch = replay_trace(n_requests)
     scores = compute_stream_scores(batch)
     cap = 8 * GiB
     print(f"\n-- replay engines, {n_requests:,} requests "
@@ -97,7 +79,7 @@ def bench_replay_speedup(rows: list[Row], n_requests: int) -> None:
 
 
 def bench_fleet_sweep(rows: list[Row], n_requests: int) -> None:
-    batch = _make_trace(max(n_requests, 1_000_000), seed=1)
+    batch = replay_trace(max(n_requests, 1_000_000), seed=1)
     fleet_ssd = batch.total_bytes // 2
 
     print(f"\n-- fleet sweep, {batch.num_requests:,} requests, "

@@ -47,6 +47,7 @@ from benchmarks import (  # noqa: E402
     bench_tileio,
 )
 from benchmarks.common import BENCH_BYTES, PAPER_BYTES, Row  # noqa: E402
+from repro.runtime import use_compile_cache  # noqa: E402
 from repro.testing import perf  # noqa: E402
 
 SUITES = {
@@ -93,6 +94,7 @@ def main(argv: Sequence[str] | None = None) -> int:
                     help="artifact directory")
     args = ap.parse_args(argv)
 
+    use_compile_cache()
     tb = PAPER_BYTES if args.full else BENCH_BYTES
     names = list(SUITES) if not args.only else args.only.split(",")
     unknown = [n for n in names if n not in SUITES]

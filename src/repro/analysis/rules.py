@@ -89,7 +89,7 @@ class UnseededRandomRule(Rule):
 
 
 # ---------------------------------------------------------------------------
-# SL102 — x64 mutation outside the scoped context manager
+# SL102 — 64-bit mode outside the one scoped helper
 # ---------------------------------------------------------------------------
 
 
@@ -98,9 +98,13 @@ class UnscopedX64Rule(Rule):
     name = "unscoped-x64"
     description = (
         "global jax_enable_x64 toggles leak float64 into every caller "
-        "and invalidate jit caches; use the scoped "
-        "jax.experimental.enable_x64() context manager."
+        "and invalidate jit caches; open 64-bit mode only as "
+        "`with repro.runtime.x64():`, the one helper built on "
+        "jax.enable_x64(True)."
     )
+
+    _HELPER_MODULE = "runtime.py"
+    _USE = " — use `with repro.runtime.x64():`"
 
     def check(self, ctx: ModuleContext) -> Iterable[Finding]:
         with_items = {
@@ -108,44 +112,35 @@ class UnscopedX64Rule(Rule):
             for node in ast.walk(ctx.tree) if isinstance(node, ast.With)
             for item in node.items
         }
+        in_helper = ctx.rel.split("/")[-1] == self._HELPER_MODULE
         for node in ast.walk(ctx.tree):
+            message = None
             if isinstance(node, ast.Call):
                 callee = _dotted(node.func)
+                last = callee.split(".")[-1]
                 if callee.endswith("config.update") and node.args:
                     arg0 = node.args[0]
                     if (isinstance(arg0, ast.Constant)
                             and arg0.value == "jax_enable_x64"):
-                        f = ctx.finding(
-                            self, node,
-                            "global jax.config.update('jax_enable_x64', ...)"
-                            " — use the scoped enable_x64() context manager",
-                        )
-                        if f:
-                            yield f
-                elif (callee.split(".")[-1] == "enable_x64"
-                        and node not in with_items):
-                    f = ctx.finding(
-                        self, node,
-                        "enable_x64() called outside a `with` statement — "
-                        "the toggle never scopes back",
-                    )
-                    if f:
-                        yield f
+                        message = ("global jax.config.update("
+                                   "'jax_enable_x64', ...)")
+                elif last in ("enable_x64", "x64") and node not in with_items:
+                    message = (f"{last}() called outside a `with` "
+                               "statement — the toggle never scopes back")
+                elif last == "enable_x64" and not in_helper:
+                    message = "jax.enable_x64() outside the x64 helper"
             elif isinstance(node, (ast.Assign, ast.AugAssign)):
                 targets = (
                     node.targets if isinstance(node, ast.Assign)
                     else [node.target]
                 )
-                for t in targets:
-                    if (isinstance(t, ast.Attribute)
-                            and t.attr == "jax_enable_x64"):
-                        f = ctx.finding(
-                            self, node,
-                            "direct assignment to jax_enable_x64 — use the "
-                            "scoped enable_x64() context manager",
-                        )
-                        if f:
-                            yield f
+                if any(isinstance(t, ast.Attribute)
+                       and t.attr == "jax_enable_x64" for t in targets):
+                    message = "direct assignment to jax_enable_x64"
+            if message:
+                f = ctx.finding(self, node, message + self._USE)
+                if f:
+                    yield f
 
 
 # ---------------------------------------------------------------------------

@@ -38,10 +38,10 @@ Structure (the state-struct / transition / orchestration split):
   :class:`~repro.core.simulator.SimResult` (the ``engine="device"`` path
   of :class:`IONodeSimulator`).
 
-Dtype policy: the engine runs under a scoped ``jax.experimental
-.enable_x64`` — clocks/rates in float64, byte counters in int64 — so the
-numbers track the numpy oracle at f64 resolution instead of drifting
-through float32.
+Dtype policy: the engine runs under the scoped 64-bit mode of
+:func:`repro.runtime.x64` — clocks/rates in float64, byte counters in
+int64 — so the numbers track the numpy oracle at f64 resolution instead
+of drifting through float32.
 
 Accuracy contract (vs the bit-exact numpy engines): the device engine is
 *stream-granular* where the oracle is request-granular.  The documented
@@ -102,16 +102,13 @@ from typing import Mapping, Sequence
 
 import numpy as np
 
-try:  # the control plane must import even where jax is absent
-    import jax
-    import jax.numpy as jnp
-    from jax import lax
-    from jax.experimental import checkify, enable_x64
-except Exception:  # pragma: no cover - jax is installed in this repo
-    jax = None
-    jnp = None
+import jax
+import jax.numpy as jnp
+from jax import lax
+from jax.experimental import checkify
 
 from ..analysis import sanitize as _sanitize
+from ..runtime import x64
 
 from .adaptive import (
     DEFAULT_THRESHOLD,
@@ -185,13 +182,6 @@ _EVENT_FIELDS = {
     **{f"wn_{i}": np.float64 for i in range(N_WINDOWS)},
     **{f"xm_{d}": np.float64 for d in range(1, XMERGE_D + 1)},
 }
-
-
-def _require_jax():
-    if jax is None:  # pragma: no cover - jax is installed in this repo
-        raise RuntimeError(
-            "engine='device' requires jax; use engine='batched' instead"
-        )
 
 
 # ---------------------------------------------------------------------------
@@ -1254,9 +1244,8 @@ def replay_lanes(
     :class:`~repro.analysis.sanitize.SanitizerError`.
     """
 
-    _require_jax()
     g = _globals(hdd or HDDModel(), interference or InterferenceModel())
-    with enable_x64():
+    with x64():
         out = _jitted_program()(
             g, dict(lanes), dict(state0), dict(events)
         )
@@ -1308,7 +1297,6 @@ def simulate_device(
 
     from .simulator import SimResult  # deferred: simulator imports us lazily
 
-    _require_jax()
     tape = build_events(
         batch, scores, stream_len=stream_len, hdd=hdd, ssd=ssd, link=link
     )

@@ -29,12 +29,11 @@ from __future__ import annotations
 import dataclasses
 from typing import Iterable, Iterator, Sequence
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
-try:  # the control plane must import even where jax is absent
-    import jax.numpy as jnp
-except Exception:  # pragma: no cover - jax is installed in this repo
-    jnp = None
+from ..runtime import x64
 
 DEFAULT_STREAM_LEN = 128  # paper: CFQ queue size, Section 2.3.1
 
@@ -167,32 +166,36 @@ def stream_stats_batch(offsets, sizes):
 def stream_stats_batch64(offsets, sizes):
     """Exact int64/float64 device scoring — bit-equal to the numpy oracle.
 
-    Same math as :func:`stream_stats_batch`, run under a scoped
-    ``jax.experimental.enable_x64`` so offsets/sizes ride true int64 lanes
+    Same math as :func:`stream_stats_batch`, run under the scoped
+    :func:`repro.runtime.x64` so offsets/sizes ride true int64 lanes
     and the percentage divides in float64.  This removes BOTH device-dtype
     caveats: offsets above 2 GiB no longer truncate, and the seek-distance
     sum accumulates as int64 with no float32 rounding.  ``(M, N)`` ->
     ``(rf int64, percentage float64, seek_distance int64)``.
 
     The scope is per-call: the global jax x64 flag is untouched, so f32
-    kernels elsewhere in the process are unaffected.
+    kernels elsewhere in the process are unaffected.  The math is one
+    jitted program, compiled once per matrix shape.
     """
 
-    from jax.experimental import enable_x64
-
-    with enable_x64():
+    with x64():
         offs = jnp.asarray(np.asarray(offsets, dtype=np.int64))
         szs = jnp.broadcast_to(
             jnp.asarray(np.asarray(sizes, dtype=np.int64)), offs.shape)
-        n = offs.shape[-1]
-        order = jnp.argsort(offs, axis=-1, stable=True)
-        so = jnp.take_along_axis(offs, order, axis=-1)
-        ss = jnp.take_along_axis(szs, order, axis=-1)
-        resid = so[..., 1:] - so[..., :-1] - ss[..., :-1]
-        rf = jnp.sum((resid != 0).astype(jnp.int64), axis=-1)
-        pct = rf.astype(jnp.float64) / max(n - 1, 1)
-        dist = jnp.sum(jnp.abs(resid), axis=-1)
-        return rf, pct, dist
+        return _stream_stats64(offs, szs)
+
+
+@jax.jit
+def _stream_stats64(offs, szs):
+    n = offs.shape[-1]
+    order = jnp.argsort(offs, axis=-1, stable=True)
+    so = jnp.take_along_axis(offs, order, axis=-1)
+    ss = jnp.take_along_axis(szs, order, axis=-1)
+    resid = so[..., 1:] - so[..., :-1] - ss[..., :-1]
+    rf = jnp.sum((resid != 0).astype(jnp.int64), axis=-1)
+    pct = rf.astype(jnp.float64) / max(n - 1, 1)
+    dist = jnp.sum(jnp.abs(resid), axis=-1)
+    return rf, pct, dist
 
 
 def stream_stats_batch_np(offsets, sizes):

@@ -21,10 +21,10 @@ fleet layer (:mod:`repro.core.fleet`):
   :func:`repro.core.random_factor.stream_stats_batch64` under a scoped
   x64 enable — int64 lanes, float64 division, bit-exact at any offset
   magnitude), and ``pallas`` (the fused ``repro.kernels.stream_rf``
-  TPU kernel; int32 lanes, so traces with offsets/sizes above 2 GiB fall
-  back to the exact host path, and the float32 seek-distance sum is
-  rounded back to integer bytes).  Both device backends fall back to
-  ``numpy`` automatically when jax is absent.
+  TPU kernel; int32 lanes, so a trace with offsets or sizes beyond
+  ``2**31 - 1`` is refused with a ``ValueError``; exact below that).
+  No backend falls back to another: :attr:`StreamScores.backend` names
+  the path that ran.
 
 Stream grouping follows :class:`repro.core.random_factor.StreamGrouper`
 semantics exactly: requests are blocked in arrival order into windows of
@@ -41,7 +41,12 @@ from typing import Iterable, Sequence
 
 import numpy as np
 
-from .random_factor import DEFAULT_STREAM_LEN, Request, stream_stats_batch_np
+from .random_factor import (
+    DEFAULT_STREAM_LEN,
+    Request,
+    stream_stats_batch64,
+    stream_stats_batch_np,
+)
 
 
 @dataclasses.dataclass(frozen=True, slots=True)
@@ -332,7 +337,7 @@ class StreamScores:                             # __eq__ would raise
     nbytes: np.ndarray  # (S,) int64
     offset_sum: np.ndarray  # (S,) int64
     stream_len: int
-    backend: str
+    backend: str  # the path that ran: numpy, jnp, pallas, pallas-interpret
 
     def __len__(self) -> int:
         return int(self.rf_sum.shape[0])
@@ -363,7 +368,8 @@ _INT32_MAX = np.int64(2**31 - 1)
 
 
 def _score_streams_device(
-    offs2d: np.ndarray, szs2d: np.ndarray, lens: np.ndarray, backend: str
+    offs2d: np.ndarray, szs2d: np.ndarray, lens: np.ndarray, backend: str,
+    interpret: bool,
 ) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
     """Score the padded (S, L) stream matrix on device.
 
@@ -372,51 +378,52 @@ def _score_streams_device(
     float64, so ``pct`` is bit-equal to the numpy oracle's division for
     every backend.
 
-    ``jnp`` runs :func:`repro.core.random_factor.stream_stats_batch64`
-    under a scoped x64 enable — int64 lanes, float64 division — and is
-    bit-exact at any offset magnitude.  ``pallas`` keeps the kernel's
-    int32/float32 lanes: offsets or sizes above 2 GiB would TRUNCATE into
-    wrong seek counts (not just imprecise ones), so those traces fall back
-    to the exact host path, and the float32 distance sum is rounded back
-    to integer bytes.
+    ``jnp`` runs :func:`repro.core.random_factor.stream_stats_batch64` in
+    the scoped 64-bit mode — int64 lanes, exact at any offset magnitude.
+    ``pallas`` runs the fused kernel on int32 lanes, exact there; offsets
+    or sizes beyond int32 would TRUNCATE into wrong seek counts, so such a
+    trace is refused.
     """
 
-    from . import random_factor as rf_mod
-
-    pallas_overflow = backend == "pallas" and (
-        np.abs(offs2d).max(initial=0) > _INT32_MAX
-        or szs2d.max(initial=0) > _INT32_MAX
-    )
-    if rf_mod.jnp is None or pallas_overflow:
-        rf, _, dist = stream_stats_batch_np(offs2d, szs2d)
-    elif backend == "pallas":
+    if backend == "pallas":
+        if (np.abs(offs2d).max(initial=0) > _INT32_MAX
+                or szs2d.max(initial=0) > _INT32_MAX):
+            raise ValueError(
+                "backend='pallas' scores on int32 lanes and this trace has "
+                "offsets or sizes beyond 2**31 - 1; backend='jnp' is the "
+                "exact device backend for it (int64 lanes)"
+            )
         from repro.kernels.stream_rf.ops import stream_stats_op
 
-        rf, _, dist = stream_stats_op(offs2d, szs2d)
+        rf, dist = stream_stats_op(offs2d, szs2d, interpret=interpret)
     else:
-        rf, _, dist = rf_mod.stream_stats_batch64(offs2d, szs2d)
+        rf, _, dist = stream_stats_batch64(offs2d, szs2d)
     rf = np.asarray(rf, dtype=np.int64)
     pct = rf / np.maximum(lens - 1, 1)
-    dist = np.rint(np.asarray(dist, dtype=np.float64)).astype(np.int64)
-    return rf, pct, dist
+    return rf, pct, np.asarray(dist, dtype=np.int64)
 
 
 def compute_stream_scores(
     trace: "TraceBatch | Sequence[TraceItem]",
     stream_len: int = DEFAULT_STREAM_LEN,
     backend: str = "numpy",
+    interpret: bool = False,
 ) -> StreamScores:
     """Score every stream of a trace in one vectorized pass.
 
-    ``backend="numpy"`` (default) is bit-exact against the scalar
-    ``stream_percentage`` / ``sorted_seek_distance`` path and needs no
-    accelerator.  ``"jnp"`` runs every stream — trailing partial included,
-    via the score-neutral padding of :meth:`TraceBatch.padded_stream_matrix`
-    — as ONE device call under a scoped x64 enable, bit-exact against the
-    oracle.  ``"pallas"`` routes the same padded matrix through the fused
-    ``stream_rf`` bitonic-sort kernel (int32 lanes: requires power-of-two
-    ``stream_len`` and offsets below 2 GiB, else it falls back to the exact
-    host path).
+    Accuracy contract: every backend is bit-exact against the scalar
+    ``stream_percentage`` / ``sorted_seek_distance`` definitions.
+
+    ``backend="numpy"`` (default) needs no accelerator.  ``"jnp"`` runs
+    every stream — trailing partial included, via the score-neutral
+    padding of :meth:`TraceBatch.padded_stream_matrix` — as ONE device
+    call in the scoped 64-bit mode.  ``"pallas"`` routes the same padded
+    matrix through the fused ``stream_rf`` bitonic-sort kernel (int32
+    lanes: requires power-of-two ``stream_len``, and raises
+    ``ValueError`` on offsets or sizes beyond ``2**31 - 1``).  The kernel
+    is compiled for the TPU unless ``interpret=True`` runs it in the
+    Pallas interpreter; the result's ``backend`` is then
+    ``"pallas-interpret"``.
     """
 
     if backend not in SCORE_BACKENDS:
@@ -442,7 +449,9 @@ def compute_stream_scores(
     else:
         offs_p, szs_p, lens = batch.padded_stream_matrix(stream_len)
         if offs_p.shape[0]:
-            rf, pct, dist = _score_streams_device(offs_p, szs_p, lens, backend)
+            rf, pct, dist = _score_streams_device(
+                offs_p, szs_p, lens, backend, interpret
+            )
         else:
             rf = np.zeros(0, dtype=np.int64)
             pct = np.zeros(0, dtype=np.float64)
@@ -455,5 +464,8 @@ def compute_stream_scores(
         nbytes=np.asarray(nbytes, dtype=np.int64),
         offset_sum=np.asarray(osum, dtype=np.int64),
         stream_len=stream_len,
-        backend=backend,
+        backend=(
+            "pallas-interpret" if backend == "pallas" and interpret
+            else backend
+        ),
     )

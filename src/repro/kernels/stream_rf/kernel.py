@@ -1,21 +1,24 @@
 """Pallas TPU kernel: batched random-factor scoring of request streams.
 
 The paper's hot loop (sort 128 offsets, count non-contiguous neighbours) as
-a TPU data-plane op.  GPU ports of sorting lean on warp shuffles; the TPU
-adaptation (DESIGN.md §2) maps the fixed-size sort onto a **bitonic
-sorting network over the 128-lane minor axis** — no data-dependent control
-flow, every compare-exchange is a full-width vector op, and the partner
-exchange for stride j is a reshape to (..., groups, 2, j) + flip of the
-pair axis, which Mosaic lowers to lane shuffles.  Sizes ride along as a
-payload through the same network.
+a TPU data-plane op.  The fixed-size sort is a **bitonic sorting network
+over the 128-lane minor axis** — no data-dependent control flow, every
+compare-exchange a full-width vector op.  Sizes ride along as a payload
+through the same network.
 
-Tiling: one VMEM block = (BLOCK_STREAMS, N) int32 for offsets + sizes plus
-a (BLOCK_STREAMS,) output tile; with BLOCK_STREAMS=256 and N=128 that is
-2 x 128 KiB in + 1 KiB out per grid step — far under the ~16 MiB VMEM
-budget, sized to keep the (8, 128) VPU tiles saturated.
+Mosaic lowers lane rotations (``pltpu.roll``) but neither flips nor
+unaligned lane slices, so both the partner exchange of a stage (lane
+``i ^ j``) and the sorted-neighbour residual (lane ``i + 1``) are built
+from rotations plus lane masks (:func:`_from_lane`).
+
+Tiling: one VMEM block = (BLOCK_STREAMS, N) int32 for offsets + sizes and
+a (BLOCK_STREAMS, 1) int32 column per statistic; with BLOCK_STREAMS=256
+and N=128 that is 2 x 128 KiB in per grid step, far under the VMEM
+budget.  Outputs are 2-D columns because the TPU tiling refuses 1-D
+output blocks narrower than 1024 elements.
 
 N must be a power of two (the stream length is the CFQ window, 128 by
-default; the host groups partial tails before calling in).
+default; the host pads partial tails before calling in).
 """
 
 from __future__ import annotations
@@ -25,22 +28,32 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 BLOCK_STREAMS = 256
 
 
+def _from_lane(x, src, d: int):
+    """Per lane ``i``, ``x[:, src[i]]`` where every ``src[i]`` is ``i + d``
+    or ``i - d`` (mod N): a select between the two rotations by ``d``.
+
+    The source of each rotated lane is read back from a rotated iota, so
+    the select does not depend on which way the hardware rotate counts.
+    """
+
+    n = x.shape[1]
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    fwd = pltpu.roll(lane, d, 1) == src
+    return jnp.where(fwd, pltpu.roll(x, d, 1), pltpu.roll(x, n - d, 1))
+
+
 def _compare_exchange(keys, payload, j: int, up_mask):
-    """One bitonic stage: partner = lane XOR j via reshape+flip."""
+    """One bitonic stage: each lane meets its partner ``lane ^ j``."""
 
-    bs, n = keys.shape
-    g = n // (2 * j)
-
-    def partner(x):
-        return jnp.flip(x.reshape(bs, g, 2, j), axis=2).reshape(bs, n)
-
-    pk = partner(keys)
-    pp = partner(payload)
-    lane = jax.lax.broadcasted_iota(jnp.int32, (bs, n), 1)
+    lane = jax.lax.broadcasted_iota(jnp.int32, keys.shape, 1)
+    partner = lane ^ j
+    pk = _from_lane(keys, partner, j)
+    pp = _from_lane(payload, partner, j)
     first = (lane & j) == 0  # lower element of each pair
     take_max = up_mask != first  # see bitonic min/max selection rule
     a_is_small = keys <= pk
@@ -69,28 +82,72 @@ def _bitonic_sort_with_payload(keys, payload):
     return keys, payload
 
 
-def _stream_rf_kernel(off_ref, size_ref, out_ref):
-    offs = off_ref[...]
-    szs = size_ref[...]
-    so, ss = _bitonic_sort_with_payload(offs, szs)
-    gaps = so[:, 1:] - so[:, :-1]
-    rf = (gaps != ss[:, :-1]).astype(jnp.int32)
-    out_ref[...] = jnp.sum(rf, axis=1)
+def _sorted_residuals(off_ref, size_ref):
+    """Sorted-neighbour residuals ``so[i+1] - so[i] - ss[i]`` and the mask
+    of the N - 1 lanes that hold one (the last lane has no neighbour)."""
+
+    so, ss = _bitonic_sort_with_payload(off_ref[...], size_ref[...])
+    n = so.shape[1]
+    lane = jax.lax.broadcasted_iota(jnp.int32, so.shape, 1)
+    nxt = _from_lane(so, lane + 1, 1)
+    return nxt - so - ss, lane < n - 1
 
 
-def _stream_stats_kernel(off_ref, size_ref, rf_ref, dist_ref):
+def _stream_rf_kernel(off_ref, size_ref, rf_ref):
+    resid, has = _sorted_residuals(off_ref, size_ref)
+    seek = has & (resid != 0)
+    rf_ref[...] = jnp.sum(seek.astype(jnp.int32), axis=1, keepdims=True)
+
+
+def _stream_stats_kernel(off_ref, size_ref, rf_ref, hi_ref, lo_ref):
     """Fused variant: Eq. 1 seek count + Eq. 6 seek-distance aggregate.
 
-    One bitonic sort feeds both reductions; the distance rides float32
-    lanes because 127 residuals of up to 2 GiB overflow int32.
+    One bitonic sort feeds both reductions.  A residual's magnitude is
+    below 2**31, but N - 1 of them overflow int32, so the distance leaves
+    as two exact int32 sums — of the high and of the low 16 bits of each
+    magnitude — for the host to join in int64.
     """
 
-    offs = off_ref[...]
-    szs = size_ref[...]
-    so, ss = _bitonic_sort_with_payload(offs, szs)
-    resid = so[:, 1:] - so[:, :-1] - ss[:, :-1]
-    rf_ref[...] = jnp.sum((resid != 0).astype(jnp.int32), axis=1)
-    dist_ref[...] = jnp.sum(jnp.abs(resid).astype(jnp.float32), axis=1)
+    resid, has = _sorted_residuals(off_ref, size_ref)
+    mag = jnp.where(has, jnp.abs(resid), 0)
+    seek = has & (resid != 0)
+    rf_ref[...] = jnp.sum(seek.astype(jnp.int32), axis=1, keepdims=True)
+    hi_ref[...] = jnp.sum(mag >> 16, axis=1, keepdims=True)
+    lo_ref[...] = jnp.sum(mag & 0xFFFF, axis=1, keepdims=True)
+
+
+def _call(kernel, n_out: int, offsets, sizes, block_streams: int,
+          interpret: bool):
+    """Pad the (M, N) stream matrix to whole blocks and run ``kernel``;
+    returns ``n_out`` int32 ``(M,)`` columns."""
+
+    m, n = offsets.shape
+    if n & (n - 1) != 0:
+        raise ValueError(f"stream length {n} must be a power of two")
+    offsets = jnp.asarray(offsets, jnp.int32)
+    sizes = jnp.broadcast_to(jnp.asarray(sizes, jnp.int32), offsets.shape)
+
+    bs = min(block_streams, m) if m else block_streams
+    pad = (-m) % bs
+    if pad:
+        # padded rows are contiguous streams -> every statistic 0; sliced
+        # off below
+        offsets = jnp.pad(offsets, ((0, pad), (0, 0)))
+        sizes = jnp.pad(sizes, ((0, pad), (0, 0)))
+    mp = offsets.shape[0]
+    col = pl.BlockSpec((bs, 1), lambda i: (i, 0))
+    outs = pl.pallas_call(
+        kernel,
+        grid=(mp // bs,),
+        in_specs=[
+            pl.BlockSpec((bs, n), lambda i: (i, 0)),
+            pl.BlockSpec((bs, n), lambda i: (i, 0)),
+        ],
+        out_specs=[col] * n_out,
+        out_shape=[jax.ShapeDtypeStruct((mp, 1), jnp.int32)] * n_out,
+        interpret=interpret,
+    )(offsets, sizes)
+    return tuple(o[:m, 0] for o in outs)
 
 
 @functools.partial(jax.jit, static_argnames=("block_streams", "interpret"))
@@ -103,75 +160,24 @@ def stream_rf(offsets: jax.Array, sizes: jax.Array,
     two (assignment default 128 = the CFQ queue window).
     """
 
-    m, n = offsets.shape
-    if n & (n - 1) != 0:
-        raise ValueError(f"stream length {n} must be a power of two")
-    offsets = jnp.asarray(offsets, jnp.int32)
-    sizes = jnp.broadcast_to(jnp.asarray(sizes, jnp.int32), offsets.shape)
-
-    bs = min(block_streams, m) if m else block_streams
-    pad = (-m) % bs
-    if pad:
-        # padded rows are contiguous streams -> rf 0; sliced off below
-        offsets = jnp.pad(offsets, ((0, pad), (0, 0)))
-        sizes = jnp.pad(sizes, ((0, pad), (0, 0)))
-    mp = offsets.shape[0]
-
-    out = pl.pallas_call(
-        _stream_rf_kernel,
-        grid=(mp // bs,),
-        in_specs=[
-            pl.BlockSpec((bs, n), lambda i: (i, 0)),
-            pl.BlockSpec((bs, n), lambda i: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((bs,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((mp,), jnp.int32),
-        interpret=interpret,
-    )(offsets, sizes)
-    return out[:m]
+    (rf,) = _call(_stream_rf_kernel, 1, offsets, sizes, block_streams,
+                  interpret)
+    return rf
 
 
 @functools.partial(jax.jit, static_argnames=("block_streams", "interpret"))
 def stream_stats(offsets: jax.Array, sizes: jax.Array,
                  block_streams: int = BLOCK_STREAMS,
-                 interpret: bool = False) -> tuple[jax.Array, jax.Array]:
-    """Fused RF + seek-distance: (M, N) int32 -> ((M,) int32, (M,) float32).
+                 interpret: bool = False
+                 ) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """Fused RF + seek distance: (M, N) int32 -> three (M,) int32 columns
+    ``(rf, dist_hi, dist_lo)``; the exact distance is
+    ``dist_hi * 2**16 + dist_lo`` (join it in int64).
 
-    Same tiling and padding contract as :func:`stream_rf`, with a second
-    per-stream output tile (the float32 seek-distance sum) written from the
-    same sorted block — the flush-cost model (Eq. 6) needs both and the
-    sort dominates, so fusing halves the kernel work vs two dispatches.
+    Same tiling and padding contract as :func:`stream_rf`: the flush-cost
+    model (Eq. 6) needs both statistics and the sort dominates, so one
+    dispatch serves both.
     """
 
-    m, n = offsets.shape
-    if n & (n - 1) != 0:
-        raise ValueError(f"stream length {n} must be a power of two")
-    offsets = jnp.asarray(offsets, jnp.int32)
-    sizes = jnp.broadcast_to(jnp.asarray(sizes, jnp.int32), offsets.shape)
-
-    bs = min(block_streams, m) if m else block_streams
-    pad = (-m) % bs
-    if pad:
-        # padded rows are contiguous streams -> rf 0, dist 0; sliced below
-        offsets = jnp.pad(offsets, ((0, pad), (0, 0)))
-        sizes = jnp.pad(sizes, ((0, pad), (0, 0)))
-    mp = offsets.shape[0]
-
-    rf, dist = pl.pallas_call(
-        _stream_stats_kernel,
-        grid=(mp // bs,),
-        in_specs=[
-            pl.BlockSpec((bs, n), lambda i: (i, 0)),
-            pl.BlockSpec((bs, n), lambda i: (i, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((bs,), lambda i: (i,)),
-            pl.BlockSpec((bs,), lambda i: (i,)),
-        ],
-        out_shape=(
-            jax.ShapeDtypeStruct((mp,), jnp.int32),
-            jax.ShapeDtypeStruct((mp,), jnp.float32),
-        ),
-        interpret=interpret,
-    )(offsets, sizes)
-    return rf[:m], dist[:m]
+    return _call(_stream_stats_kernel, 3, offsets, sizes, block_streams,
+                 interpret)

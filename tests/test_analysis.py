@@ -65,16 +65,29 @@ class TestSL102UnscopedX64:
 
     def test_flags_unscoped_enable_call(self):
         assert_flags("SL102", """
-            from jax.experimental import enable_x64
-            enable_x64()
+            import jax
+            jax.enable_x64(True)
+        """)
+        assert_flags("SL102", """
+            from repro.runtime import x64
+            x64()
         """)
 
     def test_allows_scoped_context(self):
         assert_clean("SL102", """
-            from jax.experimental import enable_x64
-            with enable_x64():
+            from repro.runtime import x64
+            with x64():
                 pass
         """)
+
+    def test_flags_enable_outside_helper(self):
+        source = """
+            import jax
+            with jax.enable_x64(True):
+                pass
+        """
+        assert_flags("SL102", source)
+        assert_clean("SL102", source, rel="repro/runtime.py")
 
 
 class TestSL103TracedBranch:

@@ -25,7 +25,7 @@ class TestStreamRF:
         rng = np.random.default_rng(m * 1000 + n)
         offs = rng.integers(0, 1 << 24, size=(m, n)).astype(np.int32)
         szs = rng.integers(1, 1 << 10, size=(m, n)).astype(np.int32)
-        got = np.asarray(stream_rf_op(offs, szs))
+        got = np.asarray(stream_rf_op(offs, szs, interpret=True))
         want = np.asarray(stream_rf_ref(offs, szs))
         np.testing.assert_array_equal(got, want)
 
@@ -35,25 +35,25 @@ class TestStreamRF:
         rng = np.random.default_rng(7)
         offs = rng.integers(0, 1 << 20, size=(16, 128)).astype(np.int32)
         szs = np.full((16, 128), 256, np.int32)
-        got = np.asarray(stream_rf_op(offs, szs))
+        got = np.asarray(stream_rf_op(offs, szs, interpret=True))
         want = np.asarray(random_factor_batch(offs, szs))
         np.testing.assert_array_equal(got, want)
 
     def test_contiguous_and_reversed(self):
         offs = (np.arange(128, dtype=np.int32) * 64)[None]
         szs = np.full((1, 128), 64, np.int32)
-        assert int(stream_rf_op(offs, szs)[0]) == 0
-        assert int(stream_rf_op(offs[:, ::-1].copy(), szs)[0]) == 0  # sorted away
+        assert int(stream_rf_op(offs, szs, interpret=True)[0]) == 0
+        assert int(stream_rf_op(offs[:, ::-1].copy(), szs, interpret=True)[0]) == 0  # sorted away
 
     def test_fully_random(self):
         offs = (np.arange(128, dtype=np.int32) * 1000)[None]
         szs = np.full((1, 128), 64, np.int32)
-        assert int(stream_rf_op(offs, szs)[0]) == 127
+        assert int(stream_rf_op(offs, szs, interpret=True)[0]) == 127
 
     def test_percentage(self):
         offs = (np.arange(128, dtype=np.int32) * 1000)[None]
         szs = np.full((1, 128), 64, np.int32)
-        assert float(random_percentage_op(offs, szs)[0]) == pytest.approx(1.0)
+        assert float(random_percentage_op(offs, szs, interpret=True)[0]) == pytest.approx(1.0)
 
     def test_block_boundary_padding(self):
         """M not divisible by the stream block: padded rows must not leak."""
@@ -61,7 +61,7 @@ class TestStreamRF:
         rng = np.random.default_rng(9)
         offs = rng.integers(0, 1 << 20, size=(5, 128)).astype(np.int32)
         szs = np.full((5, 128), 17, np.int32)
-        got = np.asarray(stream_rf_op(offs, szs, block_streams=4))
+        got = np.asarray(stream_rf_op(offs, szs, interpret=True, block_streams=4))
         want = np.asarray(stream_rf_ref(offs, szs))
         np.testing.assert_array_equal(got, want)
 

@@ -1,24 +1,20 @@
 """Scoring-backend parity: ``jnp`` and ``pallas`` vs the numpy oracle.
 
 ``compute_stream_scores`` has three backends; the numpy path is the
-int64 bit-exact oracle.  The ``jnp`` backend runs under a scoped x64
-enable (int64 lanes, float64 division) and must be BIT-EXACT on every
-field at any offset magnitude; the ``pallas`` backend keeps the fused
-kernel's int32/float32 lanes, so its seek count and percentage are exact
-while the seek distance carries float32 accumulation rounding.  Both
+int64 bit-exact oracle.  The ``jnp`` backend runs in the scoped 64-bit
+mode (int64 lanes, float64 division) and must be BIT-EXACT on every
+field at any offset magnitude.  The ``pallas`` backend keeps the fused
+kernel's int32 lanes and is bit-exact inside them (here in the Pallas
+interpreter; ``chip_smoke.py`` runs it compiled on the TPU).  Both
 backends score the trailing partial stream on device via the
-score-neutral padded row (``TraceBatch.padded_stream_matrix``), and
-traces whose offsets overflow the kernel's int32 lanes fall back to the
-exact host path.
-
-Requires jax: without it the device backends silently fall back to the
-host path and parity would be vacuous.
+score-neutral padded row (``TraceBatch.padded_stream_matrix``), and a
+trace whose offsets overflow the kernel's int32 lanes is refused, never
+scored elsewhere.
 """
 
+import jax
 import numpy as np
 import pytest
-
-jax = pytest.importorskip("jax")
 
 from repro.core import TraceBatch, compute_stream_scores, ior, mixed, relabel
 from repro.core.workloads import MiB
@@ -43,8 +39,8 @@ def _nontrivial_batch(tail: int = 0) -> TraceBatch:
     if tail:
         items = items[:-tail]
     batch = TraceBatch.from_items(items)
-    # keep offsets inside the pallas kernel's int32 lanes so this exercises
-    # the kernel itself, not the overflow fallback (tested separately)
+    # keep offsets inside the pallas kernel's int32 lanes (the refusal
+    # beyond them is tested separately)
     assert int(batch.offsets.max()) < np.iinfo(np.int32).max
     return batch
 
@@ -59,34 +55,26 @@ def ragged_batch():
     return _nontrivial_batch(tail=37)
 
 
+def _score(batch, backend):
+    """Score on ``backend``; the kernel runs in the Pallas interpreter
+    because these tests run on the CPU."""
+
+    return compute_stream_scores(batch, STREAM_LEN, backend=backend,
+                                 interpret=backend == "pallas")
+
+
 def _assert_parity(batch, backend):
     oracle = compute_stream_scores(batch, STREAM_LEN, backend="numpy")
-    scores = compute_stream_scores(batch, STREAM_LEN, backend=backend)
-    assert scores.backend == backend
+    scores = _score(batch, backend)
     assert len(scores) == len(oracle)
-    # the random factor is integer counting — bit-exact, no tolerance
-    np.testing.assert_array_equal(
-        np.asarray(scores.rf_sum, dtype=np.int64),
-        np.asarray(oracle.rf_sum, dtype=np.int64),
-        err_msg=f"{backend}: rf_sum diverged from numpy oracle")
-    # percentage = rf / (true_len - 1), divided host-side in float64 for
-    # every backend — bit-exact, including the padded trailing partial
-    np.testing.assert_array_equal(
-        scores.percentage, oracle.percentage,
-        err_msg=f"{backend}: percentage diverged")
-    if backend == "jnp":
-        # int64 lanes under scoped x64: the distance sum is exact too
+    # every statistic is integer counting or an exact int64 sum, and the
+    # percentage divides host-side in float64 for every backend —
+    # bit-exact, including the padded trailing partial
+    for field in ("rf_sum", "percentage", "seek_distance", "nbytes",
+                  "offset_sum"):
         np.testing.assert_array_equal(
-            scores.seek_distance, oracle.seek_distance,
-            err_msg="jnp: seek_distance diverged (x64 path must be exact)")
-    else:
-        # the pallas kernel accumulates |sorted residual| in float32
-        np.testing.assert_allclose(
-            scores.seek_distance, oracle.seek_distance, rtol=1e-5,
-            err_msg=f"{backend}: seek_distance diverged")
-    # byte sums are exact in every backend
-    np.testing.assert_array_equal(scores.nbytes, oracle.nbytes)
-    np.testing.assert_array_equal(scores.offset_sum, oracle.offset_sum)
+            getattr(scores, field), getattr(oracle, field),
+            err_msg=f"{backend}: {field} diverged from numpy oracle")
 
 
 @pytest.mark.parametrize("backend", ["jnp", "pallas"])
@@ -122,14 +110,9 @@ def test_padded_tail_is_score_neutral(ragged_batch):
     assert dist_pad[0] == dist_true[0]
 
 
-@pytest.mark.parametrize("backend", ["jnp", "pallas"])
-def test_huge_offsets_stay_exact(backend):
-    """Offsets beyond int32: jnp's x64 lanes handle them natively; pallas
-    must detect the overflow and fall back to the exact host path rather
-    than truncate into wrong seek counts."""
-
+def _huge_offset_batch() -> TraceBatch:
     offs = np.array([2**33, 2**33 + 4096, 2**34, 5, 2**31], dtype=np.int64)
-    batch = TraceBatch(
+    return TraceBatch(
         offsets=offs,
         sizes=np.full(offs.size, 4096, dtype=np.int64),
         file_ids=np.zeros(offs.size, dtype=np.int64),
@@ -138,11 +121,45 @@ def test_huge_offsets_stay_exact(backend):
         gap_positions=np.zeros(0, dtype=np.int64),
         gap_seconds=np.zeros(0, dtype=np.float64),
     )
+
+
+@pytest.mark.parametrize("backend", ["jnp", "pallas"])
+def test_huge_offsets_stay_exact(backend):
+    """Offsets beyond int32: jnp's x64 lanes handle them natively; pallas
+    must refuse them — naming the exact device backend — rather than
+    truncate into wrong seek counts or score them elsewhere."""
+
+    batch = _huge_offset_batch()
+    if backend == "pallas":
+        with pytest.raises(ValueError, match="backend='jnp'"):
+            _score(batch, backend)
+        return
     oracle = compute_stream_scores(batch, STREAM_LEN, backend="numpy")
-    scores = compute_stream_scores(batch, STREAM_LEN, backend=backend)
+    scores = _score(batch, backend)
     np.testing.assert_array_equal(scores.rf_sum, oracle.rf_sum)
     np.testing.assert_array_equal(scores.percentage, oracle.percentage)
     np.testing.assert_array_equal(scores.seek_distance, oracle.seek_distance)
+
+
+@pytest.mark.parametrize("backend,interpret,ran", [
+    ("numpy", False, "numpy"),
+    ("jnp", False, "jnp"),
+    ("pallas", True, "pallas-interpret"),
+])
+def test_scores_name_the_path_that_ran(batch, backend, interpret, ran):
+    scores = compute_stream_scores(batch, STREAM_LEN, backend=backend,
+                                   interpret=interpret)
+    assert scores.backend == ran
+
+
+def test_compiled_pallas_never_falls_back_off_tpu(batch):
+    """Without a TPU the compiled kernel cannot run; the call must fail
+    instead of quietly interpreting or scoring on the host."""
+
+    if jax.default_backend() == "tpu":
+        pytest.skip("a TPU is attached: the compiled kernel runs here")
+    with pytest.raises(ValueError, match="interpret"):
+        compute_stream_scores(batch, STREAM_LEN, backend="pallas")
 
 
 def test_routing_decisions_identical_across_backends(batch):
@@ -154,7 +171,7 @@ def test_routing_decisions_identical_across_backends(batch):
 
     results = {}
     for backend in ("numpy", "jnp", "pallas"):
-        scores = compute_stream_scores(batch, STREAM_LEN, backend=backend)
+        scores = _score(batch, backend)
         sim = IONodeSimulator(scheme="ssdup+",
                               ssd_capacity=batch.total_bytes // 2)
         r = sim.run(batch, scores=scores)

@@ -107,6 +107,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import checkify
 
+from .. import spans
 from ..analysis import sanitize as _sanitize
 from ..runtime import x64
 
@@ -412,6 +413,7 @@ def _suffix_hdd_anchors(batch, bounds: np.ndarray, hdd) -> np.ndarray:
     return out
 
 
+@spans.spanned("tape.build")
 def build_events(
     batch,
     scores,
@@ -449,7 +451,8 @@ def build_events(
     hdd_t = rf * hdd.seek_time + dist * hdd.seek_dist_coeff + nb / hdd.seq_bw
     net_t = nb / link.bw
     if ns:
-        anchors = _suffix_hdd_anchors(batch, bounds, hdd)
+        with spans.span("tape.suffix_anchors"):
+            anchors = _suffix_hdd_anchors(batch, bounds, hdd)
         # anchor 0 (whole stream) comes straight from the scores so the
         # pure-HDD path reproduces the oracle's walls bit-for-bit
         anchors[:, 0] = hdd_t
@@ -458,9 +461,12 @@ def build_events(
     if ns:
         w = np.maximum(batch.sizes / link.bw, batch.sizes / ssd.write_bw)
         ssd_w = np.add.reduceat(w, bounds[:-1])
-        wf, wn = _window_seek_anchors(batch, bounds)
-        pf = _prefix_seek_anchors(batch, bounds)
-        xm = _cross_stream_merges(batch, bounds)
+        with spans.span("tape.window_anchors"):
+            wf, wn = _window_seek_anchors(batch, bounds)
+        with spans.span("tape.prefix_anchors"):
+            pf = _prefix_seek_anchors(batch, bounds)
+        with spans.span("tape.xmerge"):
+            xm = _cross_stream_merges(batch, bounds)
     else:
         ssd_w = np.zeros(0, dtype=np.float64)
         wf = np.zeros((0, N_WINDOWS), dtype=np.float64)
@@ -484,28 +490,29 @@ def build_events(
     else:
         gaps_before = np.zeros(0, dtype=np.int64)
 
-    e = ns + ng
-    ev = {k: np.zeros(e, dtype=dt) for k, dt in _EVENT_FIELDS.items()}
-    ev["valid"][:] = True
-    s_idx = np.arange(ns) + gaps_before
-    g_idx = np.arange(ng) + np.searchsorted(
-        gaps_before, np.arange(ng), side="right"
-    )
-    ev["pct"][s_idx] = pct
-    ev["nbytes"][s_idx] = nb
-    for j in range(SUFFIX_ANCHORS + 1):
-        ev[f"hddt_{j}"][s_idx] = anchors[:, j]
-        ev[f"pf_{j}"][s_idx] = pf[:, j]
-    for i in range(N_WINDOWS):
-        ev[f"wf_{i}"][s_idx] = wf[:, i]
-        ev[f"wn_{i}"][s_idx] = wn[:, i]
-    for d in range(1, XMERGE_D + 1):
-        ev[f"xm_{d}"][s_idx] = xm[:, d - 1]
-    ev["net_t"][s_idx] = net_t
-    ev["ssd_w"][s_idx] = ssd_w
-    ev["mean_sz"][s_idx] = mean_sz
-    ev["is_gap"][g_idx] = True
-    ev["gap_sec"][g_idx] = gap_sec
+    with spans.span("tape.fill"):
+        e = ns + ng
+        ev = {k: np.zeros(e, dtype=dt) for k, dt in _EVENT_FIELDS.items()}
+        ev["valid"][:] = True
+        s_idx = np.arange(ns) + gaps_before
+        g_idx = np.arange(ng) + np.searchsorted(
+            gaps_before, np.arange(ng), side="right"
+        )
+        ev["pct"][s_idx] = pct
+        ev["nbytes"][s_idx] = nb
+        for j in range(SUFFIX_ANCHORS + 1):
+            ev[f"hddt_{j}"][s_idx] = anchors[:, j]
+            ev[f"pf_{j}"][s_idx] = pf[:, j]
+        for i in range(N_WINDOWS):
+            ev[f"wf_{i}"][s_idx] = wf[:, i]
+            ev[f"wn_{i}"][s_idx] = wn[:, i]
+        for d in range(1, XMERGE_D + 1):
+            ev[f"xm_{d}"][s_idx] = xm[:, d - 1]
+        ev["net_t"][s_idx] = net_t
+        ev["ssd_w"][s_idx] = ssd_w
+        ev["mean_sz"][s_idx] = mean_sz
+        ev["is_gap"][g_idx] = True
+        ev["gap_sec"][g_idx] = gap_sec
     return ev
 
 
@@ -518,6 +525,7 @@ def _pad_len(n: int) -> int:
     return p
 
 
+@spans.spanned("tape.stack")
 def stack_events(
     tapes: Sequence[Mapping[str, np.ndarray]], pad_to: int | None = None
 ) -> dict[str, np.ndarray]:
@@ -1218,6 +1226,7 @@ def _globals(
     }
 
 
+@spans.spanned("replay")
 def replay_lanes(
     events: Mapping[str, np.ndarray],
     lanes: Mapping[str, np.ndarray],
@@ -1246,18 +1255,24 @@ def replay_lanes(
 
     g = _globals(hdd or HDDModel(), interference or InterferenceModel())
     with x64():
-        out = _jitted_program()(
-            g, dict(lanes), dict(state0), dict(events)
-        )
-        if _sanitize.resolve(sanitize):
-            err, _ = _jitted_output_checker()(out)
-            try:
-                err.throw()
-            except Exception as e:
-                raise _sanitize.SanitizerError(
-                    f"device replay invariant violated: {e}"
-                ) from e
-        return {k: np.asarray(v) for k, v in out.items()}
+        # the call copies the numpy leaves to the device before it returns
+        # and leaves the program running
+        with spans.span("replay.upload"):
+            out = _jitted_program()(
+                g, dict(lanes), dict(state0), dict(events)
+            )
+        with spans.span("replay.run"):
+            out = spans.wait(out)
+            if _sanitize.resolve(sanitize):
+                err, _ = _jitted_output_checker()(out)
+                try:
+                    err.throw()
+                except Exception as e:
+                    raise _sanitize.SanitizerError(
+                        f"device replay invariant violated: {e}"
+                    ) from e
+        with spans.span("replay.readback"):
+            return {k: np.asarray(v) for k, v in out.items()}
 
 
 # ---------------------------------------------------------------------------

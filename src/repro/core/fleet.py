@@ -30,6 +30,7 @@ import numpy as np
 
 from repro.distributed.sharding import TRACE_POLICIES, assign_nodes
 
+from .. import spans
 from .device_model import clone_storage, make_storage_model
 from .random_factor import DEFAULT_STREAM_LEN
 from ..analysis import sanitize as _sanitize
@@ -273,8 +274,10 @@ class FleetProgram:
 
     Results carry the device engine's documented tolerances
     (:data:`repro.core.engine_device.DEVICE_TOLERANCES`) vs the numpy
-    engines; see ``benchmarks/bench_device_replay.py`` for the speedup
-    this buys.
+    engines.  What a call costs on a TPU, layer by layer, is measured by
+    ``chipbench`` (``python3 chipbench/run.py``) and broken down in
+    ``PERF.md`` section 5, from the spans of :mod:`repro.spans` that a
+    call records.
 
     Parameters mirror :class:`FleetSimulator` /
     :class:`IONodeSimulator`; ``ssd_capacity`` is per node.
@@ -343,6 +346,7 @@ class FleetProgram:
         self._tape_cache: tuple[int, TraceBatch, list, list, list] | None = None
 
     # ------------------------------------------------------------------
+    @spans.spanned("fleet.shard")
     def shard(self, batch: TraceBatch) -> list[TraceBatch]:
         assignment = assign_nodes(
             self.policy, batch.offsets, batch.file_ids, batch.app_ids,
@@ -350,6 +354,7 @@ class FleetProgram:
         )
         return batch.shard(assignment, self.num_nodes)
 
+    @spans.spanned("fleet.run")
     def run(
         self, trace: TraceBatch | Sequence[TraceItem]
     ) -> dict[str, FleetResult]:
@@ -359,6 +364,9 @@ class FleetProgram:
         ``DEVICE_TOLERANCES`` tiers against the batched numpy oracle.
         """
 
+        # the layer entries (self.shard, compute_stream_scores,
+        # ed.build_events, ed.stack_events, ed.replay_lanes) are looked
+        # up at call time, so that a caller can wrap them
         ed = self._ed
         batch = (
             trace if isinstance(trace, TraceBatch)
@@ -380,62 +388,65 @@ class FleetProgram:
                 )
                 for shard in shards
             ]
-            per_app = [ed.per_app_bytes(shard) for shard in shards]
+            with spans.span("fleet.lanes"):
+                per_app = [ed.per_app_bytes(shard) for shard in shards]
             self._tape_cache = (id(batch), batch, shards, tapes, per_app)
         # lane order is scheme-major: lane s * N + n replays shard n
         # under scheme s (every scheme reuses the same N tapes)
         events = ed.stack_events(
             [tapes[n] for _ in self.schemes for n in range(self.num_nodes)]
         )
-        lanes = ed._stack_lanes([
-            ed.lane_consts(
-                s, self.ssd_capacity, self.flush_gate, ssd=self.ssd
-            )
-            for s in self.schemes
-            for _ in range(self.num_nodes)
-        ])
-        state0 = ed._stack_lanes([
-            ed.initial_lane_state(
-                s, self.adaptive_window, self.threshold_warmup,
-                ssd=self.ssd,
-            )
-            for s in self.schemes
-            for _ in range(self.num_nodes)
-        ])
+        with spans.span("fleet.lanes"):
+            lanes = ed._stack_lanes([
+                ed.lane_consts(
+                    s, self.ssd_capacity, self.flush_gate, ssd=self.ssd
+                )
+                for s in self.schemes
+                for _ in range(self.num_nodes)
+            ])
+            state0 = ed._stack_lanes([
+                ed.initial_lane_state(
+                    s, self.adaptive_window, self.threshold_warmup,
+                    ssd=self.ssd,
+                )
+                for s in self.schemes
+                for _ in range(self.num_nodes)
+            ])
         out = ed.replay_lanes(
             events, lanes, state0,
             hdd=self.hdd, interference=self.interference,
         )
-        results: dict[str, FleetResult] = {}
-        for si, scheme in enumerate(self.schemes):
-            nodes = []
-            for n in range(self.num_nodes):
-                i = si * self.num_nodes + n
-                b_ssd = int(out["bytes_to_ssd"][i])
-                b_hdd = int(out["bytes_to_hdd_direct"][i])
-                nodes.append(SimResult(
+        with spans.span("fleet.assemble"):
+            results: dict[str, FleetResult] = {}
+            for si, scheme in enumerate(self.schemes):
+                nodes = []
+                for n in range(self.num_nodes):
+                    i = si * self.num_nodes + n
+                    b_ssd = int(out["bytes_to_ssd"][i])
+                    b_hdd = int(out["bytes_to_hdd_direct"][i])
+                    nodes.append(SimResult(
+                        scheme=scheme,
+                        io_seconds=float(out["io_seconds"][i]),
+                        total_seconds=float(out["total_seconds"][i]),
+                        total_bytes=b_ssd + b_hdd,
+                        bytes_to_ssd=b_ssd,
+                        bytes_to_hdd_direct=b_hdd,
+                        flushes=int(out["flushes"][i]),
+                        flush_paused_seconds=float(
+                            out["flush_paused_seconds"][i]
+                        ),
+                        blocked_seconds=float(out["blocked_seconds"][i]),
+                        peak_ssd_occupancy=int(out["peak_ssd_occupancy"][i]),
+                        metadata_bytes=0,
+                        per_app_bytes=per_app[n],
+                    ))
+                results[scheme] = FleetResult(
                     scheme=scheme,
-                    io_seconds=float(out["io_seconds"][i]),
-                    total_seconds=float(out["total_seconds"][i]),
-                    total_bytes=b_ssd + b_hdd,
-                    bytes_to_ssd=b_ssd,
-                    bytes_to_hdd_direct=b_hdd,
-                    flushes=int(out["flushes"][i]),
-                    flush_paused_seconds=float(
-                        out["flush_paused_seconds"][i]
-                    ),
-                    blocked_seconds=float(out["blocked_seconds"][i]),
-                    peak_ssd_occupancy=int(out["peak_ssd_occupancy"][i]),
-                    metadata_bytes=0,
-                    per_app_bytes=per_app[n],
-                ))
-            results[scheme] = FleetResult(
-                scheme=scheme,
-                policy=self.policy,
-                num_nodes=self.num_nodes,
-                node_results=tuple(nodes),
-            )
-        return results
+                    policy=self.policy,
+                    num_nodes=self.num_nodes,
+                    node_results=tuple(nodes),
+                )
+            return results
 
 
 def run_fleet_schemes(

@@ -33,6 +33,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import spans
 from ..runtime import x64
 
 DEFAULT_STREAM_LEN = 128  # paper: CFQ queue size, Section 2.3.1
@@ -179,10 +180,13 @@ def stream_stats_batch64(offsets, sizes):
     """
 
     with x64():
-        offs = jnp.asarray(np.asarray(offsets, dtype=np.int64))
-        szs = jnp.broadcast_to(
-            jnp.asarray(np.asarray(sizes, dtype=np.int64)), offs.shape)
-        return _stream_stats64(offs, szs)
+        # the copies start here; score.run waits for them with the program
+        with spans.span("score.upload"):
+            offs = jnp.asarray(np.asarray(offsets, dtype=np.int64))
+            szs = jnp.broadcast_to(
+                jnp.asarray(np.asarray(sizes, dtype=np.int64)), offs.shape)
+        with spans.span("score.run"):
+            return spans.wait(_stream_stats64(offs, szs))
 
 
 @jax.jit

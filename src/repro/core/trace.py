@@ -41,6 +41,7 @@ from typing import Iterable, Sequence
 
 import numpy as np
 
+from .. import spans
 from .random_factor import (
     DEFAULT_STREAM_LEN,
     Request,
@@ -398,11 +399,14 @@ def _score_streams_device(
         rf, dist = stream_stats_op(offs2d, szs2d, interpret=interpret)
     else:
         rf, _, dist = stream_stats_batch64(offs2d, szs2d)
-    rf = np.asarray(rf, dtype=np.int64)
+    with spans.span("score.readback"):
+        rf = np.asarray(rf, dtype=np.int64)
+        dist = np.asarray(dist, dtype=np.int64)
     pct = rf / np.maximum(lens - 1, 1)
-    return rf, pct, np.asarray(dist, dtype=np.int64)
+    return rf, pct, dist
 
 
+@spans.spanned("score")
 def compute_stream_scores(
     trace: "TraceBatch | Sequence[TraceItem]",
     stream_len: int = DEFAULT_STREAM_LEN,
@@ -429,10 +433,14 @@ def compute_stream_scores(
     if backend not in SCORE_BACKENDS:
         raise ValueError(f"backend must be one of {SCORE_BACKENDS}, got {backend!r}")
     batch = trace if isinstance(trace, TraceBatch) else TraceBatch.from_items(trace)
-    nbytes, osum = batch.stream_sums(stream_len)
+    with spans.span("score.matrix"):
+        nbytes, osum = batch.stream_sums(stream_len)
+        if backend == "numpy":
+            offs2d, szs2d, tail_offs, tail_szs = batch.stream_matrix(stream_len)
+        else:
+            offs_p, szs_p, lens = batch.padded_stream_matrix(stream_len)
 
     if backend == "numpy":
-        offs2d, szs2d, tail_offs, tail_szs = batch.stream_matrix(stream_len)
         if offs2d.shape[0]:
             rf, pct, dist = stream_stats_batch_np(offs2d, szs2d)
         else:
@@ -447,7 +455,6 @@ def compute_stream_scores(
             pct = np.concatenate([pct, tpct])
             dist = np.concatenate([dist, tdist])
     else:
-        offs_p, szs_p, lens = batch.padded_stream_matrix(stream_len)
         if offs_p.shape[0]:
             rf, pct, dist = _score_streams_device(
                 offs_p, szs_p, lens, backend, interpret

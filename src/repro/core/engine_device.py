@@ -61,17 +61,20 @@ fixtures (``device_tolerance`` metadata, checked by
 3. **Eq. 6 residual seeks come from precomputed anchors, not a live
    sort.**  A region buffering an arrival-window of a stream sorts that
    window ALONE (``LogRegion.seek_count_sorted``), which no pro-rated
-   share of the whole stream's count reproduces.  The host precomputes
-   per stream (a) exact PREFIX seek counts at ``SUFFIX_ANCHORS + 1``
-   request quantiles — every plain-BB fill and every first two-region
-   fill is prefix-aligned, so those lerp within ~2% — and (b) dyadic
+   share of the whole stream's count reproduces.  The tape build
+   computes per stream, exactly and on the device (one jitted program
+   per shard, :func:`_tape_anchors64`), (a) PREFIX seek counts at
+   ``SUFFIX_ANCHORS + 1`` request quantiles — every plain-BB fill and
+   every first two-region fill is prefix-aligned, so those lerp within
+   ~2% — and (b) dyadic
    window anchors (whole/halves/quarters/eighths, extent count +
    distinct-file baseline each) for interior fills, picked by nearest
    scale with linear partial-coverage; overwritten-extent dedup is not
    modeled (flush bytes = appended bytes).  A region holding SEVERAL
    streams sorts their union, so extents contiguous across neighbouring
    streams merge: the tape's per-stream cross-merge counts
-   (``xm_1..xm_{XMERGE_D}``, see :func:`_cross_stream_merges`) are
+   (``xm_1..xm_{XMERGE_D}``, see :func:`_cross_stream_merges`, the one
+   anchor pass left on the host: it sorts across streams) are
    subtracted for partners still in the active region — without this a
    tiled workload's flush rate is underestimated ~2× and plain-BB
    routing diverges.  Merges at stream distance > ``XMERGE_D`` stay
@@ -79,9 +82,10 @@ fixtures (``device_tolerance`` metadata, checked by
 4. **Plain-BB overflow suffixes are interpolated, not re-scored.**  The
    oracle re-scores an overflowed stream suffix from scratch (a strided
    suffix sorts far worse than its byte share of the whole stream), so
-   the device precomputes every stream's suffix HDD time at
-   ``SUFFIX_ANCHORS + 1`` request-quantile split points on the host and
-   lerps between them by byte fraction — exact for whole streams (the
+   the tape carries every stream's suffix HDD time at
+   ``SUFFIX_ANCHORS + 1`` request-quantile split points (their seek
+   counts and sums from the same device program) and the replay lerps
+   between them by byte fraction — exact for whole streams (the
    0-split anchor IS the stream's scored time) and at anchor-aligned
    splits, a few percent between anchors.
 5. Routing, threshold evolution, and therefore **byte routing for the
@@ -190,37 +194,6 @@ _EVENT_FIELDS = {
 # ---------------------------------------------------------------------------
 
 
-def _stream_extent_starts(
-    batch, bounds: np.ndarray
-) -> tuple[np.ndarray, np.ndarray]:
-    """Per-stream Eq. 6 seek statistics: ``(extent_starts, nfiles)``.
-
-    ``extent_starts`` is the stream's extent count after the per-file
-    offset sort (per file: 1 + non-contiguous breaks), i.e. exactly
-    ``LogRegion.seek_count_sorted`` for a region holding the whole
-    stream with unique extents.  ``nfiles`` (distinct files touched) is
-    the part that does NOT scale when a region holds a stream fraction:
-    each region pays the per-file baseline in full, only the breaks
-    pro-rate.  One vectorized lexsort covers all streams.
-    """
-
-    ns = len(bounds) - 1
-    sid = np.repeat(np.arange(ns, dtype=np.int64), np.diff(bounds))
-    order = np.lexsort((batch.offsets, batch.file_ids, sid))
-    so = batch.offsets[order]
-    ss = batch.sizes[order]
-    sf = batch.file_ids[order]
-    ssid = sid[order]
-    new_file = np.ones(len(so), dtype=bool)
-    new_file[1:] = (ssid[1:] != ssid[:-1]) | (sf[1:] != sf[:-1])
-    start = new_file.copy()
-    start[1:] |= so[1:] != so[:-1] + ss[:-1]
-    return (
-        np.bincount(ssid[start], minlength=ns).astype(np.float64),
-        np.bincount(ssid[new_file], minlength=ns).astype(np.float64),
-    )
-
-
 def _cross_stream_merges(batch, bounds: np.ndarray) -> np.ndarray:
     """Per-stream cross-merge counts ``(ns, XMERGE_D)``.
 
@@ -254,163 +227,165 @@ def _cross_stream_merges(batch, bounds: np.ndarray) -> np.ndarray:
     return out
 
 
-def _masked_predecessors(mask: np.ndarray) -> np.ndarray:
-    """Index of each element's nearest PRECEDING masked element (-1: none).
+def _shift_right(x, s: int, fill):
+    """``x`` moved ``s`` places along the last axis, ``fill`` shifted in."""
 
-    The anchor families below all reduce to "score a subset of a sorted
-    sequence": the subset keeps the global sort order, so the element
-    before ``v`` in the subset-restricted order is simply the nearest
-    earlier index with ``mask`` set — one ``maximum.accumulate``, no
-    re-sort.  This is what lets every anchor level reuse ONE global
-    lexsort instead of paying its own (the tape build was ~38 lexsorts
-    per shard before; it is 2 now).
+    pad = [(0, 0, 0)] * (x.ndim - 1) + [(s, -s, 0)]
+    return lax.pad(x, jnp.asarray(fill, x.dtype), pad)
+
+
+def _masked_prev(mask, idx, end):
+    """For every element, the index (-1: none) and the extent end of the
+    nearest EARLIER element of its row with ``mask`` set.
+
+    A masked subset of a sorted row keeps the row's order, so the
+    element before ``v`` in the subset is the nearest earlier masked one:
+    a forward fill, done as a doubling scan of lane shifts and selects
+    (``log2(L)`` steps; no gather, which the chip runs slowly).
     """
 
-    idx = np.arange(mask.shape[0], dtype=np.int64)
-    pidx = np.maximum.accumulate(np.where(mask, idx, -1))
-    prev = np.empty_like(pidx)
-    prev[0] = -1
-    prev[1:] = pidx[:-1]
-    return prev
+    p = _shift_right(jnp.where(mask, idx, -1), 1, -1)
+    e = _shift_right(jnp.where(mask, end, 0), 1, 0)
+    s = 1
+    while s < mask.shape[-1]:
+        e = jnp.where(p >= 0, e, _shift_right(e, s, 0))
+        p = jnp.maximum(p, _shift_right(p, s, -1))
+        s *= 2
+    return p, e
 
 
-def _window_seek_anchors(
-    batch, bounds: np.ndarray
-) -> tuple[np.ndarray, np.ndarray]:
-    """Eq. 6 seek anchors for dyadic arrival-windows of every stream.
+@jax.jit
+def _tape_anchors64(rows):
+    """Every stream's prefix, window and suffix seek anchors, one row per
+    stream: ``rows`` is ``(4, S, L)`` int64 (offsets, sizes, file ids,
+    and 1 where a request is, 0 on padding, which the trailing partial
+    stream and the rows past the last stream are; padding sits in no
+    mask).  One array, so one upload.
 
-    Returns ``(wf, wn)`` of shape ``(ns, N_WINDOWS)``: window ``(s, j)``
-    (scale ``s`` splits the stream into ``2**s`` equal-request windows)
-    is scored ALONE — extent count ``wf`` (per file: 1 + non-contiguous
-    breaks) and distinct-file baseline ``wn``.  Column layout is
-    scale-major: ``[whole, half0, half1, quarter0..3, eighth0..7]``.
-
-    One global ``(stream, file, offset)`` lexsort serves all 15 windows:
-    a window's elements keep their global sort order, so each window is
-    scored with a masked predecessor pass (:func:`_masked_predecessors`)
-    instead of its own sort.
+    Returns one ``(S,)``-column int32 array: the rows of
+    :data:`_COUNT_BLOCKS`, then the int64 rows of :data:`_SUM_BLOCKS`
+    split base ``2**31`` (all high parts, then all low parts), since the
+    chip hands an int32 array back in half the time of an int64 one.
+    Each family sorts the row alone with arrival position as the last
+    key, which reproduces the stable lexsort of the host passes
+    (``repro.testing.anchors``), and scores each anchor's masked subset
+    of that order by its masked predecessor.
     """
 
-    ns = len(bounds) - 1
-    lens = np.diff(bounds)
-    wf = np.zeros((ns, N_WINDOWS), dtype=np.float64)
-    wn = np.zeros((ns, N_WINDOWS), dtype=np.float64)
-    if batch.num_requests == 0:
-        return wf, wn
-    sid = np.repeat(np.arange(ns, dtype=np.int64), lens)
-    pos_in = np.arange(batch.num_requests, dtype=np.int64) - np.repeat(
-        bounds[:-1], lens
+    offs, sizes, files = rows[0], rows[1], rows[2]
+    s, l = offs.shape
+    idx = lax.broadcasted_iota(jnp.int32, (s, l), 1)
+    # requests fill each row from position 0, so the count is the length
+    n = jnp.sum(rows[3], axis=1, dtype=jnp.int32, keepdims=True)
+    j = jnp.arange(1, SUFFIX_ANCHORS + 1, dtype=jnp.int32)[:, None, None]
+
+    # prefix and window anchors: the (file, offset, arrival) order
+    sf, so, sp, ss = lax.sort((files, offs, idx, sizes), dimension=1,
+                              num_keys=3)
+    # index where each element's file run starts: a masked predecessor
+    # at or after it reads the same file
+    run0 = lax.cummax(
+        jnp.where(sf != _shift_right(sf, 1, -1), idx, 0), axis=1)
+    real = sp < n
+    # prefix j holds positions [0, round(j * n / A)), integer-exact
+    masks = [sp < (j * n + SUFFIX_ANCHORS // 2) // SUFFIX_ANCHORS]
+    for scale in range(WINDOW_SCALES):
+        w = 1 << scale
+        # p's window is the count of k >= 1 with round(k * n / w) <= p
+        win = jnp.minimum(((2 * sp + 1) * w - 1) // jnp.maximum(2 * n, 1),
+                          w - 1)
+        k = jnp.arange(w, dtype=jnp.int32)[:, None, None]
+        masks.append(real & (win == k))
+    m = jnp.concatenate(masks)
+    prev, end = _masked_prev(m, idx, so + ss)
+    same = m & (prev >= run0)
+    contig = same & (so == end)
+    breaks = jnp.sum(m & ~contig, axis=-1, dtype=jnp.int32)
+    files_in = jnp.sum(m & ~same, axis=-1, dtype=jnp.int32)
+
+    # suffix anchors 1..A-1: the (offset, arrival) order, files ignored
+    so, sp, ss = lax.sort((offs, idx, sizes), dimension=1, num_keys=2)
+    m = (sp >= (j[:-1] * n + SUFFIX_ANCHORS // 2) // SUFFIX_ANCHORS) & (sp < n)
+    prev, end = _masked_prev(m, idx, so + ss)
+    resid = jnp.where(m & (prev >= 0), so - end, 0)
+    rf = jnp.sum(resid != 0, axis=-1, dtype=jnp.int32)
+    dist = jnp.sum(jnp.abs(resid), axis=-1)
+    nb = jnp.sum(jnp.where(m, ss, 0), axis=-1)
+
+    hi, lo = jnp.divmod(jnp.concatenate([dist, nb]), 1 << 31)
+    return jnp.concatenate([
+        breaks, files_in[SUFFIX_ANCHORS:], rf,
+        hi.astype(jnp.int32), lo.astype(jnp.int32),
+    ])
+
+
+#: Row blocks of :func:`_tape_anchors64`'s output, in order: the prefix
+#: anchors' extent counts (anchors 1..A), the windows' extent counts
+#: and distinct-file baselines, the suffix anchors' (1..A-1)
+#: residual-seek counts; then their ``|residual|`` sums and byte sums.
+_COUNT_BLOCKS = (
+    ("pf", SUFFIX_ANCHORS), ("wf", N_WINDOWS), ("wn", N_WINDOWS),
+    ("rf", SUFFIX_ANCHORS - 1),
+)
+_SUM_BLOCKS = (("dist", SUFFIX_ANCHORS - 1), ("nb", SUFFIX_ANCHORS - 1))
+
+
+def _seek_anchors(batch, stream_len: int, hdd) -> tuple[np.ndarray, ...]:
+    """``(suffix, pf, wf, wn)``: the Eq. 6 seek anchors of every stream of
+    a shard with at least one request, as float64 tape columns.
+
+    ``pf[:, j]`` is the extent count (per file: 1 + non-contiguous
+    breaks) of the stream's arrival-order prefix of ``round(j * n / A)``
+    requests sorted alone; anchor 0 is the empty prefix.  ``wf``/``wn``
+    are the extent count and distinct-file count of each dyadic
+    arrival-window sorted alone, scale-major
+    (``[whole, half0, half1, quarter0..3, eighth0..7]``).
+    ``suffix[:, j]`` is the HDD time of the suffix from request
+    ``round(j * n / A)`` sorted alone (Eq. 1 seeks + sweep distance +
+    sequential time), as the oracle's overflow path scores it; column 0
+    (the whole stream) is left for the caller, the empty suffix A is 0.
+
+    Computed by :func:`_tape_anchors64` on the device, one call per
+    shard, with the rows padded to a power of two so that shards of
+    similar size share one compiled program.  Every count and sum is an
+    exact integer there (sums below 2**62); the sums become float64
+    here, exactly while a stream's bytes and ``|residual|`` sum stay
+    below 2**53.
+    """
+
+    r = batch.num_requests
+    ns = -(-r // stream_len)
+    s = _pad_len(ns)
+    rows = np.zeros((4, s * stream_len), dtype=np.int64)
+    rows[0, :r] = batch.offsets
+    rows[1, :r] = batch.sizes
+    rows[2, :r] = batch.file_ids
+    rows[3, :r] = 1
+    with x64():
+        with spans.span("tape.anchors.upload"):
+            rows = jax.device_put(rows.reshape(4, s, stream_len))
+        with spans.span("tape.anchors.run"):
+            out = spans.wait(_tape_anchors64(rows))
+        with spans.span("tape.anchors.readback"):
+            out = np.asarray(out)[:, :ns].astype(np.int64)
+    nc = sum(k for _, k in _COUNT_BLOCKS)
+    hi, lo = np.split(out[nc:], 2)
+    out = np.concatenate([out[:nc], hi * (1 << 31) + lo])
+    blocks, i = {}, 0
+    for name, k in _COUNT_BLOCKS + _SUM_BLOCKS:
+        blocks[name] = out[i:i + k].T
+        i += k
+    pf = np.zeros((ns, SUFFIX_ANCHORS + 1), dtype=np.float64)
+    pf[:, 1:] = blocks["pf"]
+    suffix = np.zeros((ns, SUFFIX_ANCHORS + 1), dtype=np.float64)
+    # same term order as HDDModel.write_time
+    suffix[:, 1:SUFFIX_ANCHORS] = (
+        blocks["rf"] * hdd.seek_time
+        + blocks["dist"].astype(np.float64) * hdd.seek_dist_coeff
+        + blocks["nb"].astype(np.float64) / hdd.seq_bw
     )
-    order = np.lexsort((batch.offsets, batch.file_ids, sid))
-    so = batch.offsets[order]
-    ss = batch.sizes[order]
-    sf = batch.file_ids[order]
-    sdi = sid[order]
-    spos = pos_in[order]
-    slen = lens[sdi]
-    col = 0
-    for s in range(WINDOW_SCALES):
-        w = 1 << s
-        # window of position p: boundaries sit at round(k * len / w), so
-        # p's window is the count of k >= 1 with floor(k*len/w + 0.5) <= p,
-        # i.e. 2*len*k < (2p+1)*w — integer-exact, no float quantiles
-        win = np.minimum(
-            ((2 * spos + 1) * w - 1) // np.maximum(2 * slen, 1), w - 1
-        )
-        for k in range(w):
-            m = win == k
-            prev = _masked_predecessors(m)
-            pc = np.maximum(prev, 0)
-            same = m & (prev >= 0) & (sdi[pc] == sdi) & (sf[pc] == sf)
-            contig = same & (so == so[pc] + ss[pc])
-            wf[:, col + k] = np.bincount(sdi[m & ~contig], minlength=ns)
-            wn[:, col + k] = np.bincount(sdi[m & ~same], minlength=ns)
-        col += w
-    return wf, wn
-
-
-def _prefix_seek_anchors(batch, bounds: np.ndarray) -> np.ndarray:
-    """``(ns, SUFFIX_ANCHORS + 1)`` Eq. 6 seek counts of every stream's
-    arrival-order PREFIX at the request-quantile split points.
-
-    Anchor ``j`` scores requests ``[0, round(j * n / A))`` of the stream
-    sorted alone (per file: 1 + non-contiguous breaks), i.e. exactly the
-    oracle's ``seek_count_sorted`` for a region buffering that prefix.
-    Anchor 0 (empty prefix) is 0, anchor A is the whole stream.  Every
-    plain-BB fill and every FIRST two-region fill of a stream is
-    prefix-aligned, so these anchors are exact there up to the quantile
-    lerp.  One global lexsort + one masked predecessor pass per anchor.
-    """
-
-    ns = len(bounds) - 1
-    out = np.zeros((ns, SUFFIX_ANCHORS + 1), dtype=np.float64)
-    if batch.num_requests == 0:
-        return out
-    lens = np.diff(bounds)
-    sid = np.repeat(np.arange(ns, dtype=np.int64), lens)
-    pos_in = np.arange(batch.num_requests, dtype=np.int64) - np.repeat(
-        bounds[:-1], lens
-    )
-    order = np.lexsort((batch.offsets, batch.file_ids, sid))
-    so = batch.offsets[order]
-    ss = batch.sizes[order]
-    sf = batch.file_ids[order]
-    sdi = sid[order]
-    spos = pos_in[order]
-    for j in range(1, SUFFIX_ANCHORS + 1):
-        k = np.floor(j * lens / SUFFIX_ANCHORS + 0.5).astype(np.int64)
-        m = spos < k[sdi]
-        prev = _masked_predecessors(m)
-        pc = np.maximum(prev, 0)
-        same = m & (prev >= 0) & (sdi[pc] == sdi) & (sf[pc] == sf)
-        contig = same & (so == so[pc] + ss[pc])
-        out[:, j] = np.bincount(sdi[m & ~contig], minlength=ns)
-    return out
-
-
-def _suffix_hdd_anchors(batch, bounds: np.ndarray, hdd) -> np.ndarray:
-    """``(ns, SUFFIX_ANCHORS + 1)`` HDD device times of every stream's
-    arrival-order suffix at the request-quantile split points.
-
-    Anchor ``j`` of stream ``s`` scores the suffix starting at request
-    ``round(j * n_s / SUFFIX_ANCHORS)`` exactly like the oracle's
-    overflow path (sort the suffix alone, Eq. 1 seeks + sweep distance +
-    sequential time); the last anchor (empty suffix) is 0.  One global
-    ``(stream, offset)`` lexsort + a masked predecessor pass per anchor.
-    """
-
-    ns = len(bounds) - 1
-    out = np.zeros((ns, SUFFIX_ANCHORS + 1), dtype=np.float64)
-    if batch.num_requests == 0:
-        return out
-    lens = np.diff(bounds)
-    sid = np.repeat(np.arange(ns, dtype=np.int64), lens)
-    pos_in = np.arange(batch.num_requests, dtype=np.int64) - np.repeat(
-        bounds[:-1], lens
-    )
-    order = np.lexsort((batch.offsets, sid))
-    so = batch.offsets[order]
-    ss = batch.sizes[order]
-    sdi = sid[order]
-    spos = pos_in[order]
-    szf = ss.astype(np.float64)
-    for j in range(SUFFIX_ANCHORS):
-        k = np.floor(j * lens / SUFFIX_ANCHORS + 0.5).astype(np.int64)
-        m = spos >= k[sdi]
-        prev = _masked_predecessors(m)
-        pc = np.maximum(prev, 0)
-        pair = m & (prev >= 0) & (sdi[pc] == sdi)
-        resid = np.where(pair, so - so[pc] - ss[pc], 0)
-        rf = np.bincount(sdi[pair & (resid != 0)], minlength=ns)
-        dist = np.bincount(
-            sdi, weights=np.abs(resid).astype(np.float64), minlength=ns
-        )
-        nb = np.bincount(sdi[m], weights=szf[m], minlength=ns)
-        # same term order as HDDModel.write_time
-        out[:, j] = (
-            rf * hdd.seek_time + dist * hdd.seek_dist_coeff + nb / hdd.seq_bw
-        )
-    return out
+    return (suffix, pf, blocks["wf"].astype(np.float64),
+            blocks["wn"].astype(np.float64))
 
 
 @spans.spanned("tape.build")
@@ -451,23 +426,17 @@ def build_events(
     hdd_t = rf * hdd.seek_time + dist * hdd.seek_dist_coeff + nb / hdd.seq_bw
     net_t = nb / link.bw
     if ns:
-        with spans.span("tape.suffix_anchors"):
-            anchors = _suffix_hdd_anchors(batch, bounds, hdd)
+        with spans.span("tape.anchors"):
+            anchors, pf, wf, wn = _seek_anchors(batch, stream_len, hdd)
         # anchor 0 (whole stream) comes straight from the scores so the
         # pure-HDD path reproduces the oracle's walls bit-for-bit
         anchors[:, 0] = hdd_t
-    else:
-        anchors = np.zeros((0, SUFFIX_ANCHORS + 1), dtype=np.float64)
-    if ns:
         w = np.maximum(batch.sizes / link.bw, batch.sizes / ssd.write_bw)
         ssd_w = np.add.reduceat(w, bounds[:-1])
-        with spans.span("tape.window_anchors"):
-            wf, wn = _window_seek_anchors(batch, bounds)
-        with spans.span("tape.prefix_anchors"):
-            pf = _prefix_seek_anchors(batch, bounds)
         with spans.span("tape.xmerge"):
             xm = _cross_stream_merges(batch, bounds)
     else:
+        anchors = np.zeros((0, SUFFIX_ANCHORS + 1), dtype=np.float64)
         ssd_w = np.zeros(0, dtype=np.float64)
         wf = np.zeros((0, N_WINDOWS), dtype=np.float64)
         wn = np.zeros((0, N_WINDOWS), dtype=np.float64)

@@ -82,8 +82,9 @@ def test_spans_nest_within_their_run(recorded_runs):
                    for p in rec.spans), s
     names = {s.name for s in rec.spans}
     assert {"fleet.shard", "score", "score.matrix", "score.upload", "score.run",
-            "score.readback", "tape.build", "tape.suffix_anchors", "tape.prefix_anchors",
-            "tape.window_anchors", "tape.xmerge", "tape.fill", "tape.stack", "fleet.lanes",
+            "score.readback", "tape.build", "tape.anchors", "tape.anchors.upload",
+            "tape.anchors.run", "tape.anchors.readback", "tape.xmerge", "tape.fill",
+            "tape.stack", "fleet.lanes",
             "replay", "replay.upload", "replay.run", "replay.readback",
             "fleet.assemble"} <= names
 

@@ -88,3 +88,17 @@ def test_replay_program_compiles_for_v5e(one_chip):
         compiled = ed._jitted_program().lower(*args).compile()
     out = compiled.output_shardings
     assert set(out) >= {"io_seconds", "bytes_to_ssd", "flushes"}
+
+
+def test_tape_anchor_program_compiles_for_v5e(one_chip):
+    """The tape build's seek-anchor program at a 64-node fleet's shard
+    shape (128 streams of 128 requests), 64-bit as it runs."""
+
+    with x64():
+        rows = jax.ShapeDtypeStruct((4, 128, STREAM_LEN), jnp.int64,
+                                    sharding=one_chip)
+        compiled = ed._tape_anchors64.lower(rows).compile()
+    n_rows = (sum(k for _, k in ed._COUNT_BLOCKS)
+              + 2 * sum(k for _, k in ed._SUM_BLOCKS))
+    assert compiled.out_info.shape == (n_rows, 128)
+    assert compiled.out_info.dtype == jnp.int32

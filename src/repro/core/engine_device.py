@@ -57,28 +57,49 @@ fixtures (``device_tolerance`` metadata, checked by
    otherwise.
 2. **Flush quanta accumulate in float64.**  The oracle truncates
    ``int(rate * wall)`` per request; the device accumulates
-   continuously (≤ 1 byte/request difference).
+   continuously (≤ 1 byte/request difference), except on the fills
+   whose requests it knows (item 3), which it truncates as the oracle
+   does.
 3. **Eq. 6 residual seeks come from precomputed anchors, not a live
-   sort.**  A region buffering an arrival-window of a stream sorts that
-   window ALONE (``LogRegion.seek_count_sorted``), which no pro-rated
-   share of the whole stream's count reproduces.  The tape build
-   computes per stream, exactly and on the device (one jitted program
-   per shard, :func:`_tape_anchors64`), (a) PREFIX seek counts at
+   sort**, except on ``ssdup`` lanes, whose regions the tape knows.
+   Under ``ssdup`` what a region holds does not depend on timing
+   (routing follows the percentages alone, item 5; a full SSD blocks
+   the writer; fills are whole requests), so the tape build lists every
+   region that lane fills with its exact flush cost — live bytes and
+   the seeks of its sorted union — and each stream's fills with their
+   summed per-request walls, the FTL's garbage collection included
+   (:func:`_ssdup_regions`).  The replay uses them while every stream
+   has gone where the table's route sends it (lane state ``rg_sync``,
+   checked stream by stream, so a route that drifts from the table's
+   drops the table for the rest of the lane) and its region's ordinal
+   and bytes are the ones the tape expects; there the lane's clocks
+   match the oracle to rounding.  On an FTL the walls are known up to the first collection
+   that might find no empty victim block (one that a region two or more
+   back left when it was trimmed); from there on, and on every other
+   lane, the estimates below stand.  A region buffering an
+   arrival-window of a stream sorts that window ALONE
+   (``LogRegion.seek_count_sorted``), which no pro-rated share of the
+   whole stream's count reproduces.  The tape build computes per
+   stream, exactly and on the device (one jitted program per shard,
+   :func:`_tape_anchors64`), (a) PREFIX seek counts at
    ``SUFFIX_ANCHORS + 1`` request quantiles — every plain-BB fill and
    every first two-region fill is prefix-aligned, so those lerp within
-   ~2% — and (b) dyadic
-   window anchors (whole/halves/quarters/eighths, extent count +
-   distinct-file baseline each) for interior fills, picked by nearest
-   scale with linear partial-coverage; overwritten-extent dedup is not
-   modeled (flush bytes = appended bytes).  A region holding SEVERAL
-   streams sorts their union, so extents contiguous across neighbouring
-   streams merge: the tape's per-stream cross-merge counts
-   (``xm_1..xm_{XMERGE_D}``, see :func:`_cross_stream_merges`, the one
-   anchor pass left on the host: it sorts across streams) are
-   subtracted for partners still in the active region — without this a
-   tiled workload's flush rate is underestimated ~2× and plain-BB
-   routing diverges.  Merges at stream distance > ``XMERGE_D`` stay
-   uncorrected (seeks are over-, never under-counted).
+   ~2% — and (b) dyadic window anchors (whole/halves/quarters/eighths,
+   extent count + distinct-file baseline each) for interior fills,
+   picked by nearest scale with linear partial-coverage; overwritten-
+   extent dedup is not modeled (flush bytes = appended bytes).  A
+   region holding SEVERAL streams sorts their union, so extents
+   contiguous across neighbouring streams merge: the tape's per-stream
+   cross-merge counts (``xm``, one column per stream distance, see
+   :func:`_cross_stream_merges`, a host pass: it sorts across streams)
+   are subtracted for partners still in the active region — without
+   this a tiled workload's flush rate is underestimated ~2× and plain-BB
+   routing diverges.  The depth follows the shard's farthest contiguous
+   pair (:data:`XMERGE_MIN_DEPTH`); pairs farther than
+   ``XMERGE_MAX_DEPTH`` stay uncorrected (seeks are over-, never
+   under-counted) and are counted (``tape.xmerge_beyond``).  The FTL's
+   garbage collection is charged per fill from the lane's mean victim
+   occupancy.
 4. **Plain-BB overflow suffixes are interpolated, not re-scored.**  The
    oracle re-scores an overflowed stream suffix from scratch (a strided
    suffix sorts far worse than its byte share of the whole stream), so
@@ -165,12 +186,24 @@ N_WINDOWS = (1 << WINDOW_SCALES) - 1
 #: streams merge and cost no seek (``LogRegion.seek_count_sorted``) —
 #: on tiled workloads (IOR strided) this collapses per-stream seek sums
 #: by an order of magnitude.  The tape carries, per stream, the count of
-#: sort-adjacent contiguous pairs it forms with each of its
-#: ``XMERGE_D`` predecessors; the fill loop subtracts the pairs whose
-#: partner stream is (fractionally) in the active region.  Pairs at
-#: distance > XMERGE_D are left uncorrected (the estimate stays
-#: conservative: seeks are over-, never under-counted).
-XMERGE_D = 4
+#: sort-adjacent contiguous pairs it forms with each of its ``D``
+#: predecessors (one ``(events, D)`` column, ``xm``); the fill loop
+#: subtracts the pairs whose partner stream is (fractionally) in the
+#: active region.  ``D`` follows the shard's pair distances: the least
+#: of 4, 16 and 64 (``XMERGE_MIN_DEPTH`` times powers of four) that
+#: covers the farthest pair, so that traffic whose farthest pair wanders
+#: within a bucket keeps one compiled shape.  Pairs beyond
+#: ``XMERGE_MAX_DEPTH`` stay uncorrected (seeks are over-, never
+#: under-counted) and are counted (``tape.xmerge_beyond``).  IO500
+#: ior-easy's farthest pair is 1 stream; ior-hard's 6-7 at 64 nodes,
+#: 16 at 4.
+XMERGE_MIN_DEPTH = 4
+XMERGE_MAX_DEPTH = 64
+
+#: SSDUP's static watermarks (random above ``STATIC_HIGH``, sequential
+#: below ``STATIC_LOW``, hysteresis between).
+STATIC_HIGH = 0.45
+STATIC_LOW = 0.30
 
 _EVENT_FIELDS = {
     "valid": np.bool_,
@@ -185,7 +218,11 @@ _EVENT_FIELDS = {
     **{f"pf_{j}": np.float64 for j in range(SUFFIX_ANCHORS + 1)},
     **{f"wf_{i}": np.float64 for i in range(N_WINDOWS)},
     **{f"wn_{i}": np.float64 for i in range(N_WINDOWS)},
-    **{f"xm_{d}": np.float64 for d in range(1, XMERGE_D + 1)},
+    # (events, D): merges with each of the D previous streams
+    "xm": np.float64,
+    # (events, len(SR_COLUMNS)), or (events, 0) where the tape lists no
+    # region: an ssdup lane's fills of the stream
+    "sr": np.float64,
 }
 
 
@@ -194,37 +231,57 @@ _EVENT_FIELDS = {
 # ---------------------------------------------------------------------------
 
 
-def _cross_stream_merges(batch, bounds: np.ndarray) -> np.ndarray:
-    """Per-stream cross-merge counts ``(ns, XMERGE_D)``.
+def _xmerge_depth(farthest: int) -> int:
+    """Tape depth for a shard whose farthest contiguous pair lies
+    ``farthest`` streams apart (see :data:`XMERGE_MIN_DEPTH`)."""
 
-    ``out[j, d-1]`` = contiguous pairs stream ``j`` forms with stream
-    ``j - d`` in the GLOBAL per-file offset sort (each pair assigned to
-    the later stream).  For non-overlapping extents a contiguous pair is
+    d = XMERGE_MIN_DEPTH
+    while d < min(farthest, XMERGE_MAX_DEPTH):
+        d *= 4
+    return d
+
+
+def _sort_neighbours(batch, order: np.ndarray):
+    """``(contig, overlap)`` of the shard's ``(file, offset)`` sort
+    ``order``: for each element after the first, whether it continues
+    its predecessor there (same file, starting at its end), and whether
+    any element starts inside its predecessor."""
+
+    so, sf = batch.offsets[order], batch.file_ids[order]
+    end = so[:-1] + batch.sizes[order][:-1]
+    same = sf[1:] == sf[:-1]
+    return same & (so[1:] == end), bool((same & (so[1:] < end)).any())
+
+
+def _cross_stream_merges(bounds: np.ndarray, order: np.ndarray,
+                         contig: np.ndarray):
+    """``(xm, pairs, beyond)``: per-stream cross-merge counts
+    ``(ns, D)``, the contiguous pairs they cover and those left beyond
+    ``D`` (:func:`_xmerge_depth`).
+
+    ``xm[j, d-1]`` = contiguous pairs (``contig``,
+    :func:`_sort_neighbours`) stream ``j`` forms with stream ``j - d`` in
+    the GLOBAL per-file offset sort ``order`` (each pair assigned to the
+    later stream).  For non-overlapping extents a contiguous pair is
     always sort-adjacent — an element between ``p`` and
-    ``p.offset + p.size`` would overlap ``p`` — so one global lexsort
-    suffices.  These are exactly the seeks
-    ``LogRegion.seek_count_sorted`` does NOT pay when both streams sit
-    in the same region, i.e. the gap between summing per-stream seek
-    estimates and sorting the region's union.
+    ``p.offset + p.size`` would overlap ``p`` — so one global sort
+    suffices.  These are exactly the seeks ``LogRegion.seek_count_sorted``
+    does NOT pay when both streams sit in the same region, i.e. the gap
+    between summing per-stream seek estimates and sorting the region's
+    union.
     """
 
     ns = len(bounds) - 1
-    out = np.zeros((ns, XMERGE_D), dtype=np.float64)
-    if batch.num_requests < 2:
-        return out
-    sid = np.repeat(np.arange(ns, dtype=np.int64), np.diff(bounds))
-    order = np.lexsort((batch.offsets, batch.file_ids))
-    so = batch.offsets[order]
-    ss = batch.sizes[order]
-    sf = batch.file_ids[order]
-    ssid = sid[order]
-    contig = (sf[1:] == sf[:-1]) & (so[1:] == so[:-1] + ss[:-1])
-    d = np.abs(ssid[1:] - ssid[:-1])
-    later = np.maximum(ssid[1:], ssid[:-1])
-    for k in range(1, XMERGE_D + 1):
-        sel = contig & (d == k)
-        out[:, k - 1] = np.bincount(later[sel], minlength=ns)
-    return out
+    ssid = np.repeat(np.arange(ns, dtype=np.int64), np.diff(bounds))[order]
+    i = np.flatnonzero(contig & (ssid[1:] != ssid[:-1]))
+    a, b = ssid[i], ssid[i + 1]
+    d = np.abs(b - a)
+    depth = _xmerge_depth(int(d.max(initial=0)))
+    kept = d <= depth
+    out = np.bincount(np.maximum(a, b)[kept] * depth + d[kept] - 1,
+                      minlength=ns * depth).reshape(ns, depth)
+    pairs = int(kept.sum())
+    return out.astype(np.float64), pairs, len(d) - pairs
 
 
 def _shift_right(x, s: int, fill):
@@ -330,9 +387,31 @@ _COUNT_BLOCKS = (
 _SUM_BLOCKS = (("dist", SUFFIX_ANCHORS - 1), ("nb", SUFFIX_ANCHORS - 1))
 
 
-def _seek_anchors(batch, stream_len: int, hdd) -> tuple[np.ndarray, ...]:
-    """``(suffix, pf, wf, wn)``: the Eq. 6 seek anchors of every stream of
-    a shard with at least one request, as float64 tape columns.
+def _dispatch_anchors(batch, stream_len: int):
+    """Upload a shard's requests and start :func:`_tape_anchors64` on
+    them; returns the output, still on its way back to the host, for
+    :func:`_seek_anchors`.  The host is free until then."""
+
+    r = batch.num_requests
+    s = _pad_len(-(-r // stream_len))
+    rows = np.zeros((4, s * stream_len), dtype=np.int64)
+    rows[0, :r] = batch.offsets
+    rows[1, :r] = batch.sizes
+    rows[2, :r] = batch.file_ids
+    rows[3, :r] = 1
+    with x64():
+        with spans.span("tape.anchors.upload"):
+            rows = jax.device_put(rows.reshape(4, s, stream_len))
+        with spans.span("tape.anchors.run"):
+            out = spans.wait(_tape_anchors64(rows))
+    out.copy_to_host_async()
+    return out
+
+
+def _seek_anchors(out, ns: int, hdd) -> tuple[np.ndarray, ...]:
+    """``(suffix, pf, wf, wn)``: the Eq. 6 seek anchors of the ``ns``
+    streams of a shard with at least one request, as float64 tape
+    columns, from the output of :func:`_dispatch_anchors`.
 
     ``pf[:, j]`` is the extent count (per file: 1 + non-contiguous
     breaks) of the stream's arrival-order prefix of ``round(j * n / A)``
@@ -353,21 +432,8 @@ def _seek_anchors(batch, stream_len: int, hdd) -> tuple[np.ndarray, ...]:
     below 2**53.
     """
 
-    r = batch.num_requests
-    ns = -(-r // stream_len)
-    s = _pad_len(ns)
-    rows = np.zeros((4, s * stream_len), dtype=np.int64)
-    rows[0, :r] = batch.offsets
-    rows[1, :r] = batch.sizes
-    rows[2, :r] = batch.file_ids
-    rows[3, :r] = 1
-    with x64():
-        with spans.span("tape.anchors.upload"):
-            rows = jax.device_put(rows.reshape(4, s, stream_len))
-        with spans.span("tape.anchors.run"):
-            out = spans.wait(_tape_anchors64(rows))
-        with spans.span("tape.anchors.readback"):
-            out = np.asarray(out)[:, :ns].astype(np.int64)
+    with spans.span("tape.anchors.readback"):
+        out = np.asarray(out)[:, :ns].astype(np.int64)
     nc = sum(k for _, k in _COUNT_BLOCKS)
     hi, lo = np.split(out[nc:], 2)
     out = np.concatenate([out[:nc], hi * (1 << 31) + lo])
@@ -388,6 +454,236 @@ def _seek_anchors(batch, stream_len: int, hdd) -> tuple[np.ndarray, ...]:
             blocks["wn"].astype(np.float64))
 
 
+def _ssdup_route(pct: np.ndarray, static_rand: bool) -> np.ndarray:
+    """Which streams an ``ssdup`` lane sends to the SSD: the static
+    watermarks with hysteresis and Algorithm 1, as
+    :func:`_observe_and_route` computes them on the device (a stream
+    rides the previous stream's decision; applications start on the
+    HDD)."""
+
+    route = np.zeros(len(pct), dtype=bool)
+    sr, cur = bool(static_rand), False
+    for j, p in enumerate(pct.tolist()):
+        route[j] = cur
+        if p > STATIC_HIGH:
+            sr = True
+        elif p < STATIC_LOW:
+            sr = False
+        thr = STATIC_LOW if sr else STATIC_HIGH
+        if p > thr:
+            cur = True
+        elif p < thr:
+            cur = False
+    return route
+
+
+#: Columns of an ``ssdup`` lane's region table (lane constant ``rg``,
+#: one row per region in fill order; :func:`_ssdup_regions`): the bytes
+#: appended to the region, its flush's live bytes and seeks, and its
+#: requests' count ``n``, summed foreground walls ``wall`` and the count
+#: ``g`` and wall sum ``sw`` of those whose wall is not their stream's
+#: ``w0`` (read where one stream fills the region whole).
+RG_COLUMNS = ("appended", "live", "seeks", "n", "wall", "g", "sw")
+#: Columns of the tape's ``sr`` field: per stream, what an ``ssdup``
+#: lane's fills of it are.  ``ssd``: 1 where the table's route sends the
+#: stream to the SSD; the replay keeps to the table only while every
+#: stream has gone where this says.  ``k``/``tail``: the active region's ordinal
+#: and bytes when the stream starts (``k = -1``: not known, the replay
+#: estimates); ``p``: the regions its bytes land in, ``k`` to
+#: ``k + p - 1``; for its part in the first (``1``) and the last (``l``)
+#: of them, the bytes ``b``, and ``n``, ``wall``, ``g``, ``sw`` as in
+#: :data:`RG_COLUMNS`; ``w0``: the first request's link time.  A wall is
+#: ``max(link, SSD device)`` time, the FTL's garbage collection included.
+SR_COLUMNS = ("ssd", "k", "tail", "p", "b1", "n1", "wall1", "g1", "sw1",
+              "bl", "nl", "walll", "gl", "swl", "w0")
+_RG = {name: i for i, name in enumerate(RG_COLUMNS)}
+_SR = {name: i for i, name in enumerate(SR_COLUMNS)}
+
+
+def _ftl_walls(ssd, lba, sz, starts, net):
+    """Foreground walls of an ``ssdup`` lane's SSD writes on a fresh
+    :class:`~repro.core.ftl.FTLModel`, request by request as its
+    ``charge_write`` serves them, and the index of the first request
+    from which they are not known (``len(sz)`` where all are).
+    ``starts`` holds each region's first request, then ``len(sz)``.
+
+    The regions are written in turn into alternating halves, so pages
+    are allocated in request order, and garbage collection fires where
+    the allocated pages pass the low watermark's headroom.  Each
+    collection erases up to the high watermark; its cost is known where
+    every victim is a block wholly of a region two or more back (those
+    are trimmed before the region that reuses their half is written, so
+    greedy choice finds them empty): the check keeps a lower bound on
+    such blocks, so the walls are exact up to the first collection it
+    cannot cover.
+    """
+
+    ps, ppb = ssd.page_size, ssd.pages_per_block
+    cnt = np.where(sz > 0, (lba + sz + ps - 1) // ps - lba // ps, 0)
+    pages = np.cumsum(cnt)
+    t = cnt * ssd.t_page
+    # empty blocks that each region leaves once trimmed: the blocks
+    # wholly inside its run of allocated pages, less those holding the
+    # run's first or last page, which may hold a partly covered logical
+    # page that the trim keeps
+    r_end = pages[starts[1:] - 1]
+    r_start = np.r_[0, r_end[:-1]]
+    pure = np.maximum(r_end // ppb + (-r_start // ppb)
+                      - (r_start % ppb == 0) - (r_end % ppb == 0), 0)
+    # a collection at request i erases up to the high mark, so the free
+    # pool then depends on i alone: the next fires at the first request
+    # past the headroom that i leaves
+    blocks = -(-pages // ppb)
+    headroom = (ssd.gc_high_blocks - ssd.gc_low_blocks) * ppb
+    first = (ssd.free_blocks + 1 - ssd.gc_low_blocks) * ppb
+    i = int(pages.searchsorted(first, "right"))
+    events = []
+    while i < len(sz):
+        events.append(i)
+        i = int(pages.searchsorted(blocks[i] * ppb + headroom, "right"))
+    ev = np.asarray(events, dtype=np.int64)
+    # blocks erased so far: after a collection at i, blocks[i] less the
+    # blocks the fresh FTL holds above the high mark, less the open one
+    after = blocks[ev] + ssd.gc_high_blocks - ssd.free_blocks - 1
+    before = np.r_[0, after[:-1]]
+    erased = after - before
+    ev_reg = np.searchsorted(starts, ev, "right") - 1
+    empty = np.r_[0, np.cumsum(pure)][np.maximum(ev_reg - 1, 0)] - before
+    bad = np.flatnonzero((erased > empty) | (erased <= 0))
+    known = int(ev[bad[0]]) if len(bad) else len(sz)
+    ok = ev < known
+    t[ev[ok]] += erased[ok] * (ssd.t_erase / ssd.n_channels)
+    return np.maximum(net, t), known
+
+
+def _live_and_seeks(batch, o, ro, r: int):
+    """Live bytes and seeks of ``r`` regions whose requests are ``o``
+    (in the shard's (file, offset, arrival) order), in regions ``ro``:
+    the latest copy of each (file, offset) is live, and each live extent
+    that does not continue its predecessor is a seek."""
+
+    # a small-integer key: numpy sorts it stably by radix
+    by_region = np.argsort(ro.astype(np.int16 if r < 2**15 else np.int64),
+                           kind="stable")
+    o, ro = o[by_region], ro[by_region]
+    f, off, sz = batch.file_ids[o], batch.offsets[o], batch.sizes[o]
+    last = np.ones(len(o), dtype=bool)
+    last[:-1] = (ro[1:] != ro[:-1]) | (f[1:] != f[:-1]) | (off[1:] != off[:-1])
+    ro, f, off, sz = ro[last], f[last], off[last], sz[last]
+    start = np.ones(len(ro), dtype=bool)
+    start[1:] = ((ro[1:] != ro[:-1]) | (f[1:] != f[:-1])
+                 | (off[1:] != off[:-1] + sz[:-1]))
+    return (np.bincount(ro, weights=sz, minlength=r),
+            np.bincount(ro[start], minlength=r))
+
+
+def _ssdup_regions(batch, bounds, pct, order, neighbours, cap: int,
+                   static_rand: bool, ssd, link):
+    """``(rg, sr)``: the table (:data:`RG_COLUMNS`) of every region an
+    ``ssdup`` lane fills, and the ``sr`` columns (:data:`SR_COLUMNS`) of
+    every stream.
+
+    Under ``ssdup`` what a region holds does not depend on timing: the
+    route follows the percentages alone (:func:`_ssdup_route`), a full
+    SSD blocks the writer rather than overflowing, and a region takes
+    whole requests in arrival order until the next does not fit.  So the
+    regions are known before the replay: each one's flush cost as
+    ``Region.flush_cost`` in the request-level replay has it, live bytes
+    (the latest copy of each ``(file, offset)``) and one seek per extent
+    start of the ``(file, offset)``-sorted union (``order`` is the
+    shard's stable ``(file, offset)`` sort, ``neighbours`` what
+    :func:`_sort_neighbours` finds in it), and each request's wall.
+    No rows where a request is larger than a region.
+    """
+
+    ns = len(bounds) - 1
+    sr = np.zeros((ns, len(SR_COLUMNS)), dtype=np.float64)
+    sr[:, _SR["k"]] = -1
+    empty = np.zeros((0, len(RG_COLUMNS)), dtype=np.float64)
+    lens = np.diff(bounds)
+    route = _ssdup_route(pct, static_rand)
+    sr[:, _SR["ssd"]] = route
+    to_ssd = route & (lens > 0)
+    streams = np.flatnonzero(to_ssd)
+    if not len(streams) or cap <= 0:
+        return empty, sr
+    idx = np.flatnonzero(np.repeat(to_ssd, lens))
+    sz = batch.sizes[idx]
+    cum = np.cumsum(sz)
+    starts, base = [0], 0  # each region's first request, then the end
+    while starts[-1] < len(idx):
+        e = int(np.searchsorted(cum, base + cap, side="right"))
+        if e == starts[-1]:
+            return empty, sr
+        starts.append(e)
+        base = int(cum[e - 1])
+    starts = np.asarray(starts)
+    r = len(starts) - 1
+    reg = np.repeat(np.arange(r), np.diff(starts))
+    rg = np.zeros((r, len(RG_COLUMNS)), dtype=np.float64)
+    rg[:, _RG["appended"]] = np.diff(np.r_[0, cum[starts[1:] - 1]])
+
+    # each region's flush: per (file, offset)-sorted run of its requests,
+    # one seek where a request does not continue its predecessor
+    region_of = np.full(batch.num_requests, -1, dtype=np.int64)
+    region_of[idx] = reg
+    ro = region_of[order]
+    contig, overlap = neighbours
+    if not overlap:
+        # no two extents overlap: every copy is live, and a request's
+        # predecessor in its region's order is next to it in the shard's
+        # (any request between would overlap one of them)
+        cont = contig & (ro[1:] == ro[:-1]) & (ro[1:] >= 0)
+        rg[:, _RG["live"]] = rg[:, _RG["appended"]]
+        rg[:, _RG["seeks"]] = np.diff(starts) - np.bincount(ro[1:][cont], minlength=r)
+    else:
+        rg[:, _RG["live"]], rg[:, _RG["seeks"]] = _live_and_seeks(
+            batch, order[ro >= 0], ro[ro >= 0], r)
+
+    # each request's bytes ahead of it in its region; its wall
+    before = cum - sz
+    ahead = before - np.repeat(before[starts[:-1]], np.diff(starts))
+    net = sz / link.bw
+    if getattr(ssd, "stateful", False):
+        if ssd.host_pages:  # a used FTL's state is not the fresh one
+            return rg, sr
+        wall, known = _ftl_walls(ssd, (reg % 2) * cap + ahead, sz, starts, net)
+    else:
+        wall, known = np.maximum(net, sz / ssd.write_bw), len(sz)
+
+    # sums over runs of SSD requests: differences of running totals of
+    # bytes, requests, walls, and count and walls of those not at w0,
+    # kept at the streams' and the regions' first requests
+    stop = np.cumsum(lens[streams])
+    first = stop - lens[streams]
+    w0 = net[first]
+    odd = wall != np.repeat(w0, lens[streams])
+    cuts = np.union1d(first, starts[:-1])
+    run = np.zeros((5, len(cuts) + 1))
+    np.cumsum([np.add.reduceat(sz, cuts), np.diff(np.r_[cuts, len(idx)]),
+               np.add.reduceat(wall, cuts), np.add.reduceat(odd, cuts, dtype=np.float64),
+               np.add.reduceat(np.where(odd, wall, 0.0), cuts)], axis=1, out=run[:, 1:])
+
+    def at(pos):  # running totals before the requests ``pos`` (cuts or the end)
+        return run[:, np.searchsorted(cuts, pos)]
+
+    per_region = at(starts[1:]) - at(starts[:-1])
+    for name, v in zip(("n", "wall", "g", "sw"), per_region[1:]):
+        rg[:, _RG[name]] = v
+    # a stream starts in the region the previous SSD request landed in
+    k = np.where(first > 0, reg[first - 1], 0)
+    tail = np.where(first > 0, ahead[first - 1] + sz[first - 1], 0)
+    in_first = at(np.minimum(stop, starts[k + 1])) - at(first)
+    in_last = at(stop) - at(np.maximum(first, starts[reg[stop - 1]]))
+    cols = {"k": np.where(stop <= known, k, -1), "tail": tail,
+            "p": reg[stop - 1] - k + 1, "w0": w0}
+    for i, name in enumerate(("b", "n", "wall", "g", "sw")):
+        cols[name + "1"], cols[name + "l"] = in_first[i], in_last[i]
+    for name, v in cols.items():
+        sr[streams, _SR[name]] = v
+    return rg, sr
+
+
 @spans.spanned("tape.build")
 def build_events(
     batch,
@@ -396,6 +692,8 @@ def build_events(
     hdd: HDDModel | None = None,
     ssd: "SSDModel | object | None" = None,
     link: IngestLink | None = None,
+    region_cap: int | None = None,
+    static_rand: bool = False,
 ) -> dict[str, np.ndarray]:
     """Lower one shard into its event tape (struct-of-arrays, length E).
 
@@ -404,6 +702,13 @@ def build_events(
     float64 with the oracle's exact expressions: whole-stream HDD time
     (Eq. 1 seeks + sweep + sequential), network time, the sequential sum
     of per-request SSD walls, and the per-stream score row.
+
+    With ``region_cap`` (an ``ssdup`` lane's region bytes) and the lane's
+    initial watermark state ``static_rand``, the tape also carries the
+    table of every region that lane fills (``rg``), which
+    :func:`lane_consts` hands to ``ssdup`` lanes, and each stream's fills
+    on that lane (``sr``; :func:`_ssdup_regions`); without, the table is
+    empty and no stream's fills are known.
     """
 
     hdd = hdd or HDDModel()
@@ -426,22 +731,37 @@ def build_events(
     hdd_t = rf * hdd.seek_time + dist * hdd.seek_dist_coeff + nb / hdd.seq_bw
     net_t = nb / link.bw
     if ns:
+        # the anchors' round trip to the device runs under the host passes
+        # that follow, up to their readback
         with spans.span("tape.anchors"):
-            anchors, pf, wf, wn = _seek_anchors(batch, stream_len, hdd)
+            pending = _dispatch_anchors(batch, stream_len)
+        with spans.span("tape.xmerge"):
+            order = np.lexsort((batch.offsets, batch.file_ids))
+            neighbours = _sort_neighbours(batch, order)
+            xm, pairs, beyond = _cross_stream_merges(bounds, order, neighbours[0])
+        spans.count("tape.xmerge_pairs", pairs)
+        spans.count("tape.xmerge_beyond", beyond)
+        if region_cap is not None:
+            with spans.span("tape.region_seeks"):
+                rg, sr = _ssdup_regions(batch, bounds, pct, order, neighbours,
+                                        int(region_cap), static_rand, ssd, link)
+        w = np.maximum(batch.sizes / link.bw, batch.sizes / ssd.write_bw)
+        ssd_w = np.add.reduceat(w, bounds[:-1])
+        anchors, pf, wf, wn = _seek_anchors(pending, ns, hdd)
         # anchor 0 (whole stream) comes straight from the scores so the
         # pure-HDD path reproduces the oracle's walls bit-for-bit
         anchors[:, 0] = hdd_t
-        w = np.maximum(batch.sizes / link.bw, batch.sizes / ssd.write_bw)
-        ssd_w = np.add.reduceat(w, bounds[:-1])
-        with spans.span("tape.xmerge"):
-            xm = _cross_stream_merges(batch, bounds)
     else:
         anchors = np.zeros((0, SUFFIX_ANCHORS + 1), dtype=np.float64)
         ssd_w = np.zeros(0, dtype=np.float64)
         wf = np.zeros((0, N_WINDOWS), dtype=np.float64)
         wn = np.zeros((0, N_WINDOWS), dtype=np.float64)
         pf = np.zeros((0, SUFFIX_ANCHORS + 1), dtype=np.float64)
-        xm = np.zeros((0, XMERGE_D), dtype=np.float64)
+        xm = np.zeros((0, XMERGE_MIN_DEPTH), dtype=np.float64)
+    if not ns or region_cap is None:
+        rg = np.zeros((0, len(RG_COLUMNS)), dtype=np.float64)
+    if not len(rg):  # no stream's fills can be known: no columns
+        sr = np.zeros((ns, 0), dtype=np.float64)
     mean_sz = nb / np.maximum(n_req, 1)
 
     gap_pos = batch.gap_positions
@@ -462,6 +782,8 @@ def build_events(
     with spans.span("tape.fill"):
         e = ns + ng
         ev = {k: np.zeros(e, dtype=dt) for k, dt in _EVENT_FIELDS.items()}
+        ev["xm"] = np.zeros((e, xm.shape[1]), dtype=np.float64)
+        ev["sr"] = np.zeros((e, sr.shape[1]), dtype=np.float64)
         ev["valid"][:] = True
         s_idx = np.arange(ns) + gaps_before
         g_idx = np.arange(ng) + np.searchsorted(
@@ -475,13 +797,14 @@ def build_events(
         for i in range(N_WINDOWS):
             ev[f"wf_{i}"][s_idx] = wf[:, i]
             ev[f"wn_{i}"][s_idx] = wn[:, i]
-        for d in range(1, XMERGE_D + 1):
-            ev[f"xm_{d}"][s_idx] = xm[:, d - 1]
+        ev["xm"][s_idx] = xm
+        ev["sr"][s_idx] = sr
         ev["net_t"][s_idx] = net_t
         ev["ssd_w"][s_idx] = ssd_w
         ev["mean_sz"][s_idx] = mean_sz
         ev["is_gap"][g_idx] = True
         ev["gap_sec"][g_idx] = gap_sec
+    ev["rg"] = rg
     return ev
 
 
@@ -492,6 +815,18 @@ def _pad_len(n: int) -> int:
     while p < n:
         p *= 2
     return p
+
+
+def _empty_events(s: int, lanes: int, depth: int = XMERGE_MIN_DEPTH,
+                  listed: int = len(SR_COLUMNS)):
+    """An all-padding stacked tape: ``(s, lanes)`` per field, with the
+    trailing column axis of ``xm`` (``depth``) and ``sr`` (``listed``:
+    0 where no tape lists a fill)."""
+
+    out = {k: np.zeros((s, lanes), dtype=dt) for k, dt in _EVENT_FIELDS.items()}
+    out["xm"] = np.zeros((s, lanes, depth), dtype=np.float64)
+    out["sr"] = np.zeros((s, lanes, listed), dtype=np.float64)
+    return out
 
 
 @spans.spanned("tape.stack")
@@ -511,14 +846,17 @@ def stack_events(
     s = pad_to if pad_to is not None else _pad_len(longest)
     if s < longest:
         raise ValueError(f"pad_to={s} < longest tape {longest}")
-    out = {
-        k: np.zeros((s, len(tapes)), dtype=dt)
-        for k, dt in _EVENT_FIELDS.items()
-    }
+    # a shallower tape's missing merge columns are zeros: no merges; a
+    # tape without fill columns lists none (zero rows)
+    out = _empty_events(s, len(tapes), max(t["xm"].shape[1] for t in tapes),
+                        max(t["sr"].shape[1] for t in tapes))
     for j, t in enumerate(tapes):
         n = len(t["valid"])
         for k in _EVENT_FIELDS:
-            out[k][:n, j] = t[k]
+            if out[k].ndim == 3:
+                out[k][:n, j, :t[k].shape[1]] = t[k]
+            else:
+                out[k][:n, j] = t[k]
     return out
 
 
@@ -527,9 +865,11 @@ def lane_consts(
     ssd_capacity: int,
     flush_gate: float | str = 0.5,
     ssd: object | None = None,
+    regions: np.ndarray | None = None,
+    slots: int = 1,
 ) -> dict[str, object]:
-    """Per-lane scalar constants (scheme id, region capacity, gate,
-    storage-model geometry).
+    """Per-lane constants (scheme id, region capacity, gate,
+    storage-model geometry, region flush table).
 
     ``flush_gate="device"`` (flush-gate v2) is encoded as the sentinel
     ``gate = -1.0``: the gate then follows the foreground device instead
@@ -537,6 +877,11 @@ def lane_consts(
     its page/GC geometry as ``ftl_*`` constants; stateless lanes get
     inert defaults (``ftl_on=False``) so the jitted step stays one
     program for mixed fleets.
+
+    ``regions`` is a tape's ``ssdup`` region table (``rg`` of
+    :func:`build_events` with ``region_cap``); an ``ssdup`` lane takes
+    it, padded to ``slots`` rows.  Other lanes, and rows past the
+    table, get ``appended = -1``, which no region matches.
     """
 
     if scheme not in SCHEME_IDS:
@@ -568,7 +913,15 @@ def lane_consts(
         page, tpp, terase, ppb, phys, low, high = (
             1.0, 0.0, 0.0, 1.0, 1.0, 0.0, 0.0,
         )
+    rg = np.zeros((slots, len(RG_COLUMNS)), dtype=np.float64)
+    rg[:, _RG["appended"]] = -1
+    if regions is not None and scheme == "ssdup":
+        n = len(regions)
+        if n > slots:
+            raise ValueError(f"{n} regions do not fit {slots} slots")
+        rg[:n] = regions
     return {
+        "rg": rg,
         "scheme": np.int32(SCHEME_IDS[scheme]),
         "cap": np.int64(cap),
         "gate": np.float64(gate),
@@ -581,6 +934,15 @@ def lane_consts(
         "ftl_low": np.float64(low),
         "ftl_high": np.float64(high),
     }
+
+
+def static_rand0(threshold_warmup: Sequence[float] | None) -> bool:
+    """An ``ssdup`` lane's initial watermark state after
+    ``threshold_warmup`` (the exact host policy's)."""
+
+    if threshold_warmup is None:
+        return False
+    return bool(StaticWatermarkThreshold().seed(threshold_warmup)._last_random)
 
 
 def initial_lane_state(
@@ -615,9 +977,7 @@ def initial_lane_state(
             win_n = len(recent)
             win_p = len(recent) % window
         elif scheme == "ssdup":
-            static_rand = StaticWatermarkThreshold().seed(
-                threshold_warmup
-            )._last_random
+            static_rand = static_rand0(threshold_warmup)
     # FTL occupancy columns mirror the (possibly pre-used) host model
     if ssd is not None and getattr(ssd, "stateful", False):
         ftl_free = float(ssd.free_pages)
@@ -636,10 +996,13 @@ def initial_lane_state(
         "s_used": np.int64(0),
         "peak": np.int64(0),
         "a_fs": np.float64(0.0),
-        # fraction of each of the last XMERGE_D streams buffered in the
-        # ACTIVE region (newest first) — partners for the cross-stream
-        # merge correction of the flush seek estimate
-        **{f"xf_{d}": np.float64(0.0) for d in range(1, XMERGE_D + 1)},
+        # regions scheduled for a flush so far: the active region's row
+        # of the lane's region flush table
+        "rg_n": np.int32(0),
+        # every stream so far went where the region table's route sends it
+        "rg_sync": np.bool_(True),
+        # region-fill loop iterations
+        "fill_trips": np.int32(0),
         "j_left": np.float64(0.0),
         "j_rate": np.float64(1.0),  # >0 so where() divisions stay finite
         "j_alive": np.bool_(False),
@@ -739,6 +1102,63 @@ def _observe_and_route(g, lane, st, pct):
     return dev_ssd, allowed, upd
 
 
+def _rg_row(lane, k):
+    """Row ``k`` of the lane's region table (``appended = -1`` past it)."""
+
+    rows = lane["rg"]
+    at = (jnp.arange(rows.shape[0]) == k)[:, None]
+    row = jnp.sum(jnp.where(at, rows, 0.0), axis=0)
+    return row.at[_RG["appended"]].set(
+        jnp.where(k < rows.shape[0], row[_RG["appended"]], -1.0))
+
+
+def _region_flush_cost(lane, k, appended, est_seeks, sync):
+    """``(live bytes, seeks)`` of flushing a region that holds
+    ``appended`` bytes and is the lane's ``k``-th: the lane's region
+    table row ``k`` where every stream so far went where the table's
+    route sends it (``sync``) and that row lists exactly ``appended``
+    bytes, else the appended bytes and the anchor estimate
+    ``est_seeks``."""
+
+    row = _rg_row(lane, k)
+    hit = sync & (row[_RG["appended"]] == appended)
+    live = jnp.where(hit, row[_RG["live"]].astype(jnp.int64), appended)
+    seeks = jnp.where(hit, row[_RG["seeks"]], est_seeks)
+    return live, seeks
+
+
+def _listed_fill(lane, c, ev, fill, sync):
+    """``(known, wall, progress)`` of a fill of ``fill`` bytes of a
+    stream whose ``ssdup`` fills the tape lists (``sr``,
+    :func:`_ssdup_regions`): whether the lane's region state is the one
+    the tape expects (``sync`` as in :func:`_region_flush_cost`, and the
+    region's ordinal and bytes), the fill's summed request walls, and
+    the in-flight flush's progress over them, request by request,
+    truncated to whole bytes each as the request-level replay counts it.
+    A zero row lists nothing (``p = 0``)."""
+
+    sr = ev["sr"]
+    q = c["rg_n"] - sr[_SR["k"]].astype(jnp.int32)
+    p = sr[_SR["p"]].astype(jnp.int32)
+    row = _rg_row(lane, c["rg_n"])
+    head, last = q == 0, (q > 0) & (q == p - 1)
+    n_req, wall, n_odd, odd_w = (
+        jnp.where(head, sr[_SR[a + "1"]],
+                  jnp.where(last, sr[_SR[a + "l"]], row[_RG[a]]))
+        for a in ("n", "wall", "g", "sw")
+    )
+    expect = jnp.where(head, sr[_SR["b1"]],
+                       jnp.where(last, sr[_SR["bl"]], row[_RG["appended"]]))
+    known = (
+        sync & (lane["scheme"] == 2) & (sr[_SR["k"]] >= 0) & (q >= 0) & (q < p)
+        & (c["a_used"] == jnp.where(head, sr[_SR["tail"]], 0.0).astype(jnp.int64))
+        & (fill == expect.astype(jnp.int64))
+    )
+    prog = ((n_req - n_odd) * jnp.floor(c["j_rate"] * sr[_SR["w0"]])
+            + jnp.floor(c["j_rate"] * odd_w))
+    return known, wall, prog
+
+
 def _ssd_fill_loop(g, lane, st, ev, allowed, dev_ssd):
     """SSD-routed stream: fill regions, swap/block/trigger, overflow.
 
@@ -800,11 +1220,19 @@ def _ssd_fill_loop(g, lane, st, ev, allowed, dev_ssd):
             jnp.maximum(ev["net_t"] * frac, seg_dev),
             ev["ssd_w"] * frac,
         )
+        # an ssdup lane whose region state is the one the tape expects at
+        # this fill knows the fill's requests (where the tape lists fills)
+        listed = (_listed_fill(lane, c, ev, fill, st["rg_sync"])
+                  if ev["sr"].shape[-1] else None)
+        if listed is not None:
+            segw = jnp.where(listed[0], listed[1], segw)
 
         # flush bookkeeping while the foreground writes the SSD: the job
         # drains at its full Eq. 6 effective rate (no HDD contention)
         progressing = c["j_alive"] & allowed
         prog = c["j_rate"] * segw
+        if listed is not None:
+            prog = jnp.where(listed[0], listed[2], prog)
         completed = progressing & (prog >= c["j_left"])
         # a completing flush retires its region's log: the FTL trims
         # those pages (they stop being live on flash)
@@ -873,9 +1301,10 @@ def _ssd_fill_loop(g, lane, st, ev, allowed, dev_ssd):
         # predecessor still (fractionally) in the active region cost no
         # seek once the region sorts its union; pro-rate by this fill's
         # share of the stream
-        seg_xm = wfrac * sum(
-            ev[f"xm_{d}"] * c[f"xf_{d}"] for d in range(1, XMERGE_D + 1)
-        )
+        merged = jnp.zeros_like(nb_f)
+        for d in range(ev["xm"].shape[-1]):  # in order: zero columns add 0
+            merged = merged + ev["xm"][d] * c["xf"][d]
+        seg_xm = wfrac * merged
         a_fs = jnp.maximum(c["a_fs"] + seg_fs - seg_xm, 0.0)
         b_ssd = c["b_ssd"] + fill
         rem = c["rem"] - fill
@@ -898,14 +1327,16 @@ def _ssd_fill_loop(g, lane, st, ev, allowed, dev_ssd):
         s_used = jnp.where(do_block, 0, s_used)
 
         # schedule the filled region's flush (Eq. 6: seeks = pro-rated
-        # extent-start count of the region's content)
+        # extent-start count of the region's content, or the region
+        # flush table's exact row where the region is the one it lists)
         sched = swap | bb_trig
         jb = a_used
-        jb_f = jb.astype(jnp.float64)
-        service = a_fs * g["seek_time"] + jb_f / g["seq_bw"]
-        n_rate = jnp.where(jb > 0, jb_f / service, g["seq_bw"])
+        live, seeks = _region_flush_cost(lane, c["rg_n"], jb, a_fs, st["rg_sync"])
+        live_f = live.astype(jnp.float64)
+        service = seeks * g["seek_time"] + live_f / g["seq_bw"]
+        n_rate = jnp.where(live > 0, live_f / service, g["seq_bw"])
         j_rate = jnp.where(sched, n_rate, c["j_rate"])
-        j_left = jnp.where(sched, jb_f, j_left)
+        j_left = jnp.where(sched, live_f, j_left)
         j_alive = j_alive | sched
         s_used = jnp.where(sched, jb, s_used)
         a_used = jnp.where(sched, 0, a_used)
@@ -913,10 +1344,7 @@ def _ssd_fill_loop(g, lane, st, ev, allowed, dev_ssd):
         # scheduling hands the region's content to the flusher: earlier
         # streams leave the active region, and only fills AFTER the swap
         # count toward this stream's presence in it
-        xf = {
-            f"xf_{d}": jnp.where(sched, 0.0, c[f"xf_{d}"])
-            for d in range(1, XMERGE_D + 1)
-        }
+        xf = jnp.where(sched, 0.0, c["xf"])
         cur_xf = jnp.where(sched, 0.0, c["cur_xf"] + wfrac)
 
         ovf = c["ovf"] | bb_ovf | (bb_trig & (rem > 0))
@@ -935,8 +1363,10 @@ def _ssd_fill_loop(g, lane, st, ev, allowed, dev_ssd):
             "blocked": blocked, "b_ssd": b_ssd, "flushes": flushes,
             "a_used": a_used, "s_used": s_used, "a_fs": a_fs,
             "j_left": j_left, "j_rate": j_rate, "j_alive": j_alive,
-            "cur_xf": cur_xf, "ftl_free": ftl_free, "ftl_live": ftl_live,
-            "ftl_reloc": ftl_reloc, **xf,
+            "cur_xf": cur_xf, "xf": xf, "ftl_free": ftl_free,
+            "ftl_live": ftl_live, "ftl_reloc": ftl_reloc,
+            "rg_n": c["rg_n"] + _i32(sched),
+            "fill_trips": c["fill_trips"] + 1,
         }
 
     # HDD-routed streams and capacity-less lanes (orangefs) must never
@@ -953,8 +1383,8 @@ def _ssd_fill_loop(g, lane, st, ev, allowed, dev_ssd):
         "j_alive": st["j_alive"],
         "cur_xf": jnp.zeros_like(st["a_fs"]),
         "ftl_free": st["ftl_free"], "ftl_live": st["ftl_live"],
-        "ftl_reloc": st["ftl_reloc"],
-        **{f"xf_{d}": st[f"xf_{d}"] for d in range(1, XMERGE_D + 1)},
+        "ftl_reloc": st["ftl_reloc"], "xf": st["xf"],
+        "rg_n": st["rg_n"], "fill_trips": st["fill_trips"],
     }
     return lax.while_loop(cond, body, init)
 
@@ -1046,6 +1476,11 @@ def _stream_step(g, lane, st, ev):
     is_tworeg = (scheme == 2) | (scheme == 3)
 
     dev_ssd, allowed, upd = _observe_and_route(g, lane, st, ev["pct"])
+    if ev["sr"].shape[-1]:
+        # the lane's region table holds while every stream goes where the
+        # table's route sends it; once one does not, the estimates stand
+        st = {**st, "rg_sync": st["rg_sync"]
+              & (dev_ssd == (ev["sr"][_SR["ssd"]] > 0))}
 
     c = _ssd_fill_loop(g, lane, st, ev, allowed, dev_ssd)
     # bytes headed to the HDD in the foreground: the whole stream when
@@ -1058,7 +1493,8 @@ def _stream_step(g, lane, st, ev):
         k: jnp.where(dev_ssd, c[k], st[k])
         for k in ("clock", "pause", "blocked", "b_ssd", "flushes",
                   "a_used", "s_used", "a_fs", "j_left", "j_rate",
-                  "j_alive", "ftl_free", "ftl_live", "ftl_reloc")
+                  "j_alive", "ftl_free", "ftl_live", "ftl_reloc", "rg_n",
+                  "fill_trips")
     }
     base["b_hdd"] = st["b_hdd"]
     base["gap"] = st["gap"]
@@ -1068,11 +1504,10 @@ def _stream_step(g, lane, st, ev):
     # shift the cross-merge partner window one stream: this stream's
     # active-region fraction enters at distance 1 (an HDD-routed stream
     # enters as 0 — its bytes never reached the region)
-    out["xf_1"] = jnp.where(dev_ssd, c["cur_xf"], 0.0)
-    for d in range(2, XMERGE_D + 1):
-        out[f"xf_{d}"] = jnp.where(
-            dev_ssd, c[f"xf_{d - 1}"], st[f"xf_{d - 1}"]
-        )
+    out["xf"] = jnp.concatenate([
+        jnp.where(dev_ssd, c["cur_xf"], 0.0)[None],
+        jnp.where(dev_ssd, c["xf"], st["xf"])[:-1],
+    ])
     # the oracle samples occupancy at END of stream — after the overflow
     # HDD writes, during which the flush may complete and reset the
     # region — so sample post-advance state
@@ -1085,7 +1520,7 @@ def _stream_step(g, lane, st, ev):
     # scheme (observe happens whichever device served the stream)
     for k, v in upd.items():
         out[k] = jnp.where(is_tworeg, v, st[k])
-    for k in ("win", "win_n", "win_p", "static_rand", "cur_ssd"):
+    for k in ("win", "win_n", "win_p", "static_rand", "cur_ssd", "rg_sync"):
         out.setdefault(k, st[k])
     return out
 
@@ -1101,16 +1536,21 @@ def _event_step(g, lane, st, ev):
     return {k: pick(gap[k], strm[k], st[k]) for k in st}
 
 
-def _final_drain(g, st):
+def _final_drain(g, st, lanes=None):
     """End-of-trace drain (vectorized over lanes): finish the in-flight
-    job, then flush the still-buffered active region (Eq. 6)."""
+    job, then flush the still-buffered active region (Eq. 6), costed
+    from the lanes' region flush tables where ``lanes`` is given."""
 
     d1 = jnp.where(st["j_alive"], st["j_left"] / st["j_rate"], 0.0)
     has_active = st["a_used"] > 0
-    a_f = st["a_used"].astype(jnp.float64)
+    if lanes is None:
+        live, seeks = st["a_used"], st["a_fs"]
+    else:
+        live, seeks = jax.vmap(_region_flush_cost)(
+            lanes, st["rg_n"], st["a_used"], st["a_fs"], st["rg_sync"])
     d2 = jnp.where(
         has_active,
-        st["a_fs"] * g["seek_time"] + a_f / g["seq_bw"],
+        seeks * g["seek_time"] + live.astype(jnp.float64) / g["seq_bw"],
         0.0,
     )
     total = st["clock"] + d1 + d2
@@ -1126,6 +1566,7 @@ def _final_drain(g, st):
         # FTL diagnostics (zeros on constant-backend lanes)
         "ftl_reloc_pages": st["ftl_reloc"],
         "ftl_live_pages": st["ftl_live"],
+        "fill_trips": st["fill_trips"],
     }
 
 
@@ -1136,8 +1577,12 @@ def _replay_program(g, lanes, state0, events):
         )(lanes, st, ev)
         return new, None
 
-    final, _ = lax.scan(scan_step, state0, events)
-    return _final_drain(g, final)
+    # fraction of each of the last D streams buffered in the ACTIVE
+    # region (newest first): partners for the cross-stream merge
+    # correction of the flush seek estimate
+    xf = jnp.zeros(events["xm"].shape[1:], dtype=jnp.float64)
+    final, _ = lax.scan(scan_step, {**state0, "xf": xf}, events)
+    return _final_drain(g, final, lanes)
 
 
 @functools.lru_cache(maxsize=1)
@@ -1190,8 +1635,8 @@ def _globals(
         "slowdown": np.float64(interference.foreground_slowdown()),
         "flush_frac": np.float64(interference.flush_rate_fraction()),
         "default_thr": np.float64(DEFAULT_THRESHOLD),
-        "static_high": np.float64(0.45),
-        "static_low": np.float64(0.30),
+        "static_high": np.float64(STATIC_HIGH),
+        "static_low": np.float64(STATIC_LOW),
     }
 
 
@@ -1241,7 +1686,9 @@ def replay_lanes(
                         f"device replay invariant violated: {e}"
                     ) from e
         with spans.span("replay.readback"):
-            return {k: np.asarray(v) for k, v in out.items()}
+            out = {k: np.asarray(v) for k, v in out.items()}
+    spans.count("replay.fill_trips", int(out["fill_trips"].sum()))
+    return out
 
 
 # ---------------------------------------------------------------------------
@@ -1282,12 +1729,15 @@ def simulate_device(
     from .simulator import SimResult  # deferred: simulator imports us lazily
 
     tape = build_events(
-        batch, scores, stream_len=stream_len, hdd=hdd, ssd=ssd, link=link
+        batch, scores, stream_len=stream_len, hdd=hdd, ssd=ssd, link=link,
+        region_cap=ssd_capacity // 2 if scheme == "ssdup" else None,
+        static_rand=static_rand0(threshold_warmup),
     )
     events = stack_events([tape])
-    lanes = _stack_lanes(
-        [lane_consts(scheme, ssd_capacity, flush_gate, ssd=ssd)]
-    )
+    lanes = _stack_lanes([
+        lane_consts(scheme, ssd_capacity, flush_gate, ssd=ssd, regions=tape["rg"],
+                    slots=_pad_len(len(tape["rg"])))
+    ])
     state0 = _stack_lanes(
         [initial_lane_state(scheme, adaptive_window, threshold_warmup,
                             ssd=ssd)]

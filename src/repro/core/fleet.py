@@ -377,6 +377,9 @@ class FleetProgram:
             _, _, shards, tapes, per_app = cached
         else:
             shards = self.shard(batch)
+            # ssdup lanes cost each region's flush from its exact table
+            region_cap = (self.ssd_capacity // 2 if "ssdup" in self.schemes
+                          else None)
             tapes = [
                 ed.build_events(
                     shard,
@@ -385,6 +388,8 @@ class FleetProgram:
                     ),
                     stream_len=self.stream_len,
                     hdd=self.hdd, ssd=self.ssd, link=self.link,
+                    region_cap=region_cap,
+                    static_rand=ed.static_rand0(self.threshold_warmup),
                 )
                 for shard in shards
             ]
@@ -397,12 +402,14 @@ class FleetProgram:
             [tapes[n] for _ in self.schemes for n in range(self.num_nodes)]
         )
         with spans.span("fleet.lanes"):
+            slots = ed._pad_len(max(len(t["rg"]) for t in tapes))
             lanes = ed._stack_lanes([
                 ed.lane_consts(
-                    s, self.ssd_capacity, self.flush_gate, ssd=self.ssd
+                    s, self.ssd_capacity, self.flush_gate, ssd=self.ssd,
+                    regions=tapes[n]["rg"], slots=slots,
                 )
                 for s in self.schemes
-                for _ in range(self.num_nodes)
+                for n in range(self.num_nodes)
             ])
             state0 = ed._stack_lanes([
                 ed.initial_lane_state(

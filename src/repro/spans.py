@@ -13,11 +13,13 @@ includes persistent-cache loads) are kept as :class:`Compile` events.
 
     with spans.recording() as rec:
         FleetProgram(...).run(trace)
-    rec.spans, rec.compiles
+    rec.spans, rec.counts, rec.compiles
 
 A span opened with no span open on its thread starts a new *run*: every
 span under it shares that run's number, so one ``FleetProgram.run`` call
-is one run.
+is one run.  :func:`count` keeps a named number (a :class:`Count`) under
+the innermost open span in the same way; outside :func:`recording` it
+does nothing.
 """
 
 from __future__ import annotations
@@ -69,13 +71,25 @@ class Compile:
     t1: int
 
 
+@dataclasses.dataclass(frozen=True)
+class Count:
+    """One :func:`count` call: ``span`` is the innermost span open on the
+    calling thread (``None`` outside every span), ``run`` its run."""
+
+    name: str
+    value: int
+    span: str | None
+    run: int | None
+
+
 class Recorder:
     """What one :func:`recording` block kept: the closed spans in the
-    order they closed, the compile stages, and the count of persistent
-    compile-cache hits."""
+    order they closed, the counts, the compile stages, and the count of
+    persistent compile-cache hits."""
 
     def __init__(self) -> None:
         self.spans: list[Span] = []
+        self.counts: list[Count] = []
         self.compiles: list[Compile] = []
         self.cache_hits = 0
         self._runs = 0
@@ -165,6 +179,25 @@ def spanned(name: str) -> Callable[[F], F]:
         return call  # type: ignore[return-value]
 
     return wrap
+
+
+def count(name: str, value: int) -> None:
+    """Keep ``value`` as count ``name`` while recording; nothing otherwise.
+    The caller passes a number it already holds on the host, so a count
+    adds no device synchronisation."""
+
+    rec = _active
+    if rec is None:
+        return
+    stack = rec._stack()
+    span_name, run = stack[-1] if stack else (None, None)
+    rec.counts.append(Count(name, int(value), span_name, run))
+
+
+def total(counts: list[Count], name: str) -> int:
+    """The sum of the counts named ``name``."""
+
+    return sum(c.value for c in counts if c.name == name)
 
 
 def wait(x: T) -> T:

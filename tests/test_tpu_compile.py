@@ -75,10 +75,7 @@ def test_replay_program_compiles_for_v5e(one_chip):
             lanes.append(ed.lane_consts(scheme, cap, ssd=ssd))
             state0.append(ed.initial_lane_state(scheme, 64, ssd=ssd))
     n_lanes, n_events = len(lanes), 256
-    events = {
-        k: np.zeros((n_events, n_lanes), dtype=dt)
-        for k, dt in ed._EVENT_FIELDS.items()
-    }
+    events = ed._empty_events(n_events, n_lanes)
     g = ed._globals(HDDModel(), InterferenceModel())
     args = _shapes(
         (g, ed._stack_lanes(lanes), ed._stack_lanes(state0), events),

@@ -51,7 +51,7 @@ from .random_factor import (
 )
 from .redirector import DataRedirector, Device, RoutedStream
 from .simulator import Gap, IONodeSimulator, SimResult, run_schemes
-from .trace import StreamScores, TraceBatch, compute_stream_scores
+from .trace import StreamScores, TraceBatch, TraceShards, compute_stream_scores
 from .fleet import FleetProgram, FleetResult, FleetSimulator, run_fleet_schemes
 from .workloads import (
     Workload,
@@ -102,6 +102,7 @@ __all__ = [
     "run_schemes",
     "StreamScores",
     "TraceBatch",
+    "TraceShards",
     "compute_stream_scores",
     "FleetProgram",
     "FleetResult",

@@ -39,6 +39,7 @@ from .trace import (
     SCORE_BACKENDS,
     TraceBatch,
     TraceItem,
+    TraceShards,
     compute_stream_scores,
 )
 
@@ -377,21 +378,24 @@ class FleetProgram:
             _, _, shards, tapes, per_app = cached
         else:
             shards = self.shard(batch)
+            # every shard's streams in one scoring call, one device
+            # dispatch on the device backends
+            job = TraceShards(tuple(shards))
+            scores = compute_stream_scores(
+                job, self.stream_len, backend=self.score_backend
+            ).split(job.stream_counts(self.stream_len))
             # ssdup lanes cost each region's flush from its exact table
             region_cap = (self.ssd_capacity // 2 if "ssdup" in self.schemes
                           else None)
             tapes = [
                 ed.build_events(
-                    shard,
-                    compute_stream_scores(
-                        shard, self.stream_len, backend=self.score_backend
-                    ),
+                    shard, shard_scores,
                     stream_len=self.stream_len,
                     hdd=self.hdd, ssd=self.ssd, link=self.link,
                     region_cap=region_cap,
                     static_rand=ed.static_rand0(self.threshold_warmup),
                 )
-                for shard in shards
+                for shard, shard_scores in zip(shards, scores)
             ]
             with spans.span("fleet.lanes"):
                 per_app = [ed.per_app_bytes(shard) for shard in shards]

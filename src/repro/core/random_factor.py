@@ -176,7 +176,9 @@ def stream_stats_batch64(offsets, sizes):
 
     The scope is per-call: the global jax x64 flag is untouched, so f32
     kernels elsewhere in the process are unaffected.  The math is one
-    jitted program, compiled once per matrix shape.
+    jitted program, compiled once per matrix shape; offsets and sizes
+    stay int64 on the device, and the sort carries the sizes with the
+    offsets instead of gathering them after it.
     """
 
     with x64():
@@ -192,9 +194,11 @@ def stream_stats_batch64(offsets, sizes):
 @jax.jit
 def _stream_stats64(offs, szs):
     n = offs.shape[-1]
-    order = jnp.argsort(offs, axis=-1, stable=True)
-    so = jnp.take_along_axis(offs, order, axis=-1)
-    ss = jnp.take_along_axis(szs, order, axis=-1)
+    # one stable sort keyed on the offsets carries the sizes along: the
+    # same permutation as a stable argsort, ties in arrival order, and no
+    # gathers of the int64 columns after it
+    so, ss = jax.lax.sort((offs, szs), dimension=offs.ndim - 1, num_keys=1,
+                          is_stable=True)
     resid = so[..., 1:] - so[..., :-1] - ss[..., :-1]
     rf = jnp.sum((resid != 0).astype(jnp.int64), axis=-1)
     pct = rf.astype(jnp.float64) / max(n - 1, 1)

@@ -10,6 +10,9 @@ fleet layer (:mod:`repro.core.fleet`):
   (:class:`Gap`) are stored out-of-band as ``(position, seconds)`` pairs
   where ``position`` is the request index the gap precedes.  Converts
   losslessly to/from the simulator's item lists.
+* :class:`TraceShards` — a trace's shards, which
+  :func:`compute_stream_scores` scores together: ``FleetProgram`` scores
+  every shard of a job in one device dispatch.
 * :class:`StreamScores` — the three per-stream statistics the simulator
   needs (Eq. 1 random-factor sum, random percentage, sorted seek distance),
   precomputed for *all* streams of a trace in one vectorized call so
@@ -30,8 +33,8 @@ Stream grouping follows :class:`repro.core.random_factor.StreamGrouper`
 semantics exactly: requests are blocked in arrival order into windows of
 ``stream_len``; gaps do NOT flush a partial window.  The trailing partial
 stream is padded into a score-neutral fixed-shape row
-(:meth:`TraceBatch.padded_stream_matrix`) so device backends score it in
-the same dispatch as the full windows.
+(:meth:`TraceBatch.padded_stream_matrix`) so every backend scores it in
+the same pass as the full windows, a device backend in the same dispatch.
 """
 
 from __future__ import annotations
@@ -263,25 +266,6 @@ class TraceBatch:                               # __eq__ would raise
             np.add.reduceat(self.offsets, starts),
         )
 
-    def stream_matrix(
-        self, stream_len: int = DEFAULT_STREAM_LEN
-    ) -> tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray]:
-        """``(offsets (M, L), sizes (M, L), tail_offsets, tail_sizes)``.
-
-        M full streams in arrival order plus the (possibly empty) trailing
-        partial stream, matching :class:`StreamGrouper` emission order.
-        """
-
-        r = self.num_requests
-        m = r // stream_len
-        full = m * stream_len
-        return (
-            self.offsets[:full].reshape(m, stream_len),
-            self.sizes[:full].reshape(m, stream_len),
-            self.offsets[full:],
-            self.sizes[full:],
-        )
-
     def padded_stream_matrix(
         self, stream_len: int = DEFAULT_STREAM_LEN
     ) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
@@ -299,22 +283,88 @@ class TraceBatch:                               # __eq__ would raise
         (``true_lens - 1``) applied host-side.
         """
 
-        offs2d, szs2d, tail_offs, tail_szs = self.stream_matrix(stream_len)
-        lens = np.full(offs2d.shape[0], stream_len, dtype=np.int64)
-        t = tail_offs.size
+        return _padded_streams((self,), stream_len)
+
+
+def _padded_streams(
+    shards: Sequence[TraceBatch], stream_len: int
+) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
+    """The shards' padded stream rows stacked in order, as
+    :meth:`TraceBatch.padded_stream_matrix` builds them for one trace.
+
+    The matrix has the most rows that ``R`` requests over ``S`` shards
+    can need, ``(R + S * (L - 1)) // L``, so its shape depends only on
+    the request count and the shard count, never on how the requests
+    fall; for one shard that is exactly its stream count.  Rows past the
+    shards' own are all-zero offsets and sizes with a true length of 0:
+    they score 0 seeks and 0 distance.
+    """
+
+    n_req = sum(b.num_requests for b in shards)
+    rows = (n_req + len(shards) * (stream_len - 1)) // stream_len
+    offs = np.zeros((rows, stream_len), dtype=np.int64)
+    szs = np.zeros((rows, stream_len), dtype=np.int64)
+    lens = np.zeros(rows, dtype=np.int64)
+    r = 0
+    for b in shards:
+        m = b.num_requests // stream_len
+        full = m * stream_len
+        offs[r:r + m] = b.offsets[:full].reshape(m, stream_len)
+        szs[r:r + m] = b.sizes[:full].reshape(m, stream_len)
+        lens[r:r + m] = stream_len
+        r += m
+        t = b.num_requests - full
         if t:
+            tail_offs, tail_szs = b.offsets[full:], b.sizes[full:]
             # sorted-last real request = LAST occurrence of the max offset
             # (stable sort keeps arrival order among equal offsets)
             j = t - 1 - int(np.argmax(tail_offs[::-1]))
-            pad_off = int(tail_offs[j]) + int(tail_szs[j])
-            row_o = np.concatenate(
-                [tail_offs, np.full(stream_len - t, pad_off, dtype=np.int64)])
-            row_s = np.concatenate(
-                [tail_szs, np.zeros(stream_len - t, dtype=np.int64)])
-            offs2d = np.vstack([offs2d, row_o[None, :]])
-            szs2d = np.vstack([szs2d, row_s[None, :]])
-            lens = np.append(lens, t)
-        return offs2d, szs2d, lens
+            offs[r, :t] = tail_offs
+            offs[r, t:] = int(tail_offs[j]) + int(tail_szs[j])
+            szs[r, :t] = tail_szs
+            lens[r] = t
+            r += 1
+    return offs, szs, lens
+
+
+@dataclasses.dataclass(frozen=True, eq=False)
+class TraceShards:
+    """A trace's shards, scored together in one call of
+    :func:`compute_stream_scores`.
+
+    It offers what the scoring reads from a :class:`TraceBatch`, over its
+    shards in order: stream grouping restarts at every shard, and each
+    shard's trailing partial is padded on its own.  The result covers
+    every shard's streams in order; :meth:`StreamScores.split` with
+    :meth:`stream_counts` hands each shard its own.
+    """
+
+    shards: tuple[TraceBatch, ...]
+
+    @property
+    def num_requests(self) -> int:
+        return sum(b.num_requests for b in self.shards)
+
+    def stream_counts(self, stream_len: int = DEFAULT_STREAM_LEN) -> list[int]:
+        return [b.num_streams(stream_len) for b in self.shards]
+
+    def stream_sums(
+        self, stream_len: int = DEFAULT_STREAM_LEN
+    ) -> tuple[np.ndarray, np.ndarray]:
+        """Each shard's :meth:`TraceBatch.stream_sums`, concatenated."""
+
+        sums = [b.stream_sums(stream_len) for b in self.shards]
+        z = np.zeros(0, dtype=np.int64)
+        return (np.concatenate([z, *(s[0] for s in sums)]),
+                np.concatenate([z, *(s[1] for s in sums)]))
+
+    def padded_stream_matrix(
+        self, stream_len: int = DEFAULT_STREAM_LEN
+    ) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
+        """Every shard's padded rows in one matrix, its shape fixed by the
+        request and shard counts (:func:`_padded_streams`)."""
+
+        return _padded_streams(self.shards, stream_len)
 
 
 # ---------------------------------------------------------------------------
@@ -361,6 +411,21 @@ class StreamScores:                             # __eq__ would raise
         if np.any((self.percentage < 0.0) | (self.percentage > 1.0)):
             raise ValueError("random percentage outside [0, 1]")
 
+    def split(self, counts: Sequence[int]) -> list["StreamScores"]:
+        """Consecutive parts of ``counts[i]`` streams each: one shard's
+        scores apiece, checksums included."""
+
+        if sum(counts) != len(self):
+            raise ValueError(
+                f"split into {sum(counts)} streams but the scores cover {len(self)}")
+        cuts = np.cumsum(counts)[:-1]
+        cols = ("rf_sum", "percentage", "seek_distance", "nbytes", "offset_sum")
+        parts = [np.split(getattr(self, c), cuts) for c in cols]
+        return [
+            dataclasses.replace(self, **{c: p[i] for c, p in zip(cols, parts)})
+            for i in range(len(counts))
+        ]
+
 
 SCORE_BACKENDS = ("numpy", "jnp", "pallas")
 
@@ -369,15 +434,10 @@ _INT32_MAX = np.int64(2**31 - 1)
 
 
 def _score_streams_device(
-    offs2d: np.ndarray, szs2d: np.ndarray, lens: np.ndarray, backend: str,
-    interpret: bool,
-) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
-    """Score the padded (S, L) stream matrix on device.
-
-    ``lens`` holds each row's TRUE request count (< L only for the padded
-    trailing partial); the percentage denominator uses it host-side in
-    float64, so ``pct`` is bit-equal to the numpy oracle's division for
-    every backend.
+    offs2d: np.ndarray, szs2d: np.ndarray, backend: str, interpret: bool,
+) -> tuple[np.ndarray, np.ndarray]:
+    """Seek counts and seek distances ``(rf, dist)`` of the padded
+    (S, L) stream matrix, scored on device in one dispatch.
 
     ``jnp`` runs :func:`repro.core.random_factor.stream_stats_batch64` in
     the scoped 64-bit mode — int64 lanes, exact at any offset magnitude.
@@ -400,15 +460,12 @@ def _score_streams_device(
     else:
         rf, _, dist = stream_stats_batch64(offs2d, szs2d)
     with spans.span("score.readback"):
-        rf = np.asarray(rf, dtype=np.int64)
-        dist = np.asarray(dist, dtype=np.int64)
-    pct = rf / np.maximum(lens - 1, 1)
-    return rf, pct, dist
+        return np.asarray(rf, dtype=np.int64), np.asarray(dist, dtype=np.int64)
 
 
 @spans.spanned("score")
 def compute_stream_scores(
-    trace: "TraceBatch | Sequence[TraceItem]",
+    trace: "TraceBatch | TraceShards | Sequence[TraceItem]",
     stream_len: int = DEFAULT_STREAM_LEN,
     backend: str = "numpy",
     interpret: bool = False,
@@ -418,9 +475,10 @@ def compute_stream_scores(
     Accuracy contract: every backend is bit-exact against the scalar
     ``stream_percentage`` / ``sorted_seek_distance`` definitions.
 
-    ``backend="numpy"`` (default) needs no accelerator.  ``"jnp"`` runs
-    every stream — trailing partial included, via the score-neutral
-    padding of :meth:`TraceBatch.padded_stream_matrix` — as ONE device
+    Every backend scores the padded matrix of
+    :meth:`TraceBatch.padded_stream_matrix`, trailing partial included
+    through its score-neutral padding.  ``backend="numpy"`` (default)
+    needs no accelerator.  ``"jnp"`` runs every stream as ONE device
     call in the scoped 64-bit mode.  ``"pallas"`` routes the same padded
     matrix through the fused ``stream_rf`` bitonic-sort kernel (int32
     lanes: requires power-of-two ``stream_len``, and raises
@@ -428,41 +486,32 @@ def compute_stream_scores(
     is compiled for the TPU unless ``interpret=True`` runs it in the
     Pallas interpreter; the result's ``backend`` is then
     ``"pallas-interpret"``.
+
+    Given :class:`TraceShards`, the shards' padded rows are stacked into
+    one matrix, which the device backends score in one dispatch; the
+    result covers the shards in order, and :meth:`StreamScores.split`
+    cuts it back into one per shard.
     """
 
     if backend not in SCORE_BACKENDS:
         raise ValueError(f"backend must be one of {SCORE_BACKENDS}, got {backend!r}")
-    batch = trace if isinstance(trace, TraceBatch) else TraceBatch.from_items(trace)
+    batch = (trace if isinstance(trace, (TraceBatch, TraceShards))
+             else TraceBatch.from_items(trace))
     with spans.span("score.matrix"):
         nbytes, osum = batch.stream_sums(stream_len)
-        if backend == "numpy":
-            offs2d, szs2d, tail_offs, tail_szs = batch.stream_matrix(stream_len)
-        else:
-            offs_p, szs_p, lens = batch.padded_stream_matrix(stream_len)
-
+        offs_p, szs_p, lens = batch.padded_stream_matrix(stream_len)
     if backend == "numpy":
-        if offs2d.shape[0]:
-            rf, pct, dist = stream_stats_batch_np(offs2d, szs2d)
-        else:
-            rf = np.zeros(0, dtype=np.int64)
-            pct = np.zeros(0, dtype=np.float64)
-            dist = np.zeros(0, dtype=np.int64)
-        if tail_offs.size:
-            trf, tpct, tdist = stream_stats_batch_np(
-                tail_offs[None, :], tail_szs[None, :]
-            )
-            rf = np.concatenate([rf, trf])
-            pct = np.concatenate([pct, tpct])
-            dist = np.concatenate([dist, tdist])
+        rf, _, dist = stream_stats_batch_np(offs_p, szs_p)
+    elif offs_p.shape[0]:
+        rf, dist = _score_streams_device(offs_p, szs_p, backend, interpret)
     else:
-        if offs_p.shape[0]:
-            rf, pct, dist = _score_streams_device(
-                offs_p, szs_p, lens, backend, interpret
-            )
-        else:
-            rf = np.zeros(0, dtype=np.int64)
-            pct = np.zeros(0, dtype=np.float64)
-            dist = np.zeros(0, dtype=np.int64)
+        rf = dist = np.zeros(0, dtype=np.int64)
+    # rows past the streams are the matrix's neutral padding; the
+    # percentage divides by each row's true length host-side in float64,
+    # as the oracle does
+    n = len(nbytes)
+    rf, dist = rf[:n], dist[:n]
+    pct = rf / np.maximum(lens[:n] - 1, 1)
 
     return StreamScores(
         rf_sum=rf,

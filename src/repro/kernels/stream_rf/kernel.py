@@ -4,7 +4,8 @@ The paper's hot loop (sort 128 offsets, count non-contiguous neighbours) as
 a TPU data-plane op.  The fixed-size sort is a **bitonic sorting network
 over the 128-lane minor axis** — no data-dependent control flow, every
 compare-exchange a full-width vector op.  Sizes ride along as a payload
-through the same network.
+through the same network, and each element's arrival lane breaks ties
+between equal offsets, so the network orders them as a stable sort does.
 
 Mosaic lowers lane rotations (``pltpu.roll``) but neither flips nor
 unaligned lane slices, so both the partner exchange of a stage (lane
@@ -47,36 +48,43 @@ def _from_lane(x, src, d: int):
     return jnp.where(fwd, pltpu.roll(x, d, 1), pltpu.roll(x, n - d, 1))
 
 
-def _compare_exchange(keys, payload, j: int, up_mask):
-    """One bitonic stage: each lane meets its partner ``lane ^ j``."""
+def _compare_exchange(keys, payload, order, j: int, up_mask):
+    """One bitonic stage: each lane meets its partner ``lane ^ j``.
+
+    Keys compare as ``(key, order)``, ``order`` being each element's
+    arrival lane, so equal keys keep arrival order: the network sorts as a
+    stable sort does.
+    """
 
     lane = jax.lax.broadcasted_iota(jnp.int32, keys.shape, 1)
     partner = lane ^ j
     pk = _from_lane(keys, partner, j)
     pp = _from_lane(payload, partner, j)
+    po = _from_lane(order, partner, j)
     first = (lane & j) == 0  # lower element of each pair
     take_max = up_mask != first  # see bitonic min/max selection rule
-    a_is_small = keys <= pk
-    small_k = jnp.where(a_is_small, keys, pk)
-    big_k = jnp.where(a_is_small, pk, keys)
-    small_p = jnp.where(a_is_small, payload, pp)
-    big_p = jnp.where(a_is_small, pp, payload)
-    new_k = jnp.where(take_max, big_k, small_k)
-    new_p = jnp.where(take_max, big_p, small_p)
-    return new_k, new_p
+    a_is_small = (keys < pk) | ((keys == pk) & (order < po))
+    new = []
+    for mine, theirs in ((keys, pk), (payload, pp), (order, po)):
+        small = jnp.where(a_is_small, mine, theirs)
+        big = jnp.where(a_is_small, theirs, mine)
+        new.append(jnp.where(take_max, big, small))
+    return tuple(new)
 
 
 def _bitonic_sort_with_payload(keys, payload):
-    """Ascending bitonic sort along the minor axis (power-of-two length)."""
+    """Stable ascending bitonic sort along the minor axis (power-of-two
+    length)."""
 
     bs, n = keys.shape
     lane = jax.lax.broadcasted_iota(jnp.int32, (bs, n), 1)
+    order = lane
     k = 2
     while k <= n:
         up = (lane & k) == 0
         j = k // 2
         while j >= 1:
-            keys, payload = _compare_exchange(keys, payload, j, up)
+            keys, payload, order = _compare_exchange(keys, payload, order, j, up)
             j //= 2
         k *= 2
     return keys, payload

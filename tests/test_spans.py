@@ -96,11 +96,14 @@ def test_children_of_a_run_fit_inside_it(recorded_runs):
 
 
 def test_one_score_and_tape_span_per_shard(recorded_runs, batch):
+    """Every shard is scored in the run's one score span (one device
+    dispatch) and lowered to a tape in a tape span of its own."""
+
     shards = [s for s in program().shard(batch) if s.num_requests]
+    assert len(shards) == NODES
     for run in (0, 1):
         names = [s.name for s in recorded_runs.spans if s.run == run]
-        assert names.count("score") == len(shards)
-        assert names.count("score.run") == len(shards)
+        assert names.count("score") == names.count("score.run") == 1
         assert names.count("tape.build") == len(shards)
         assert names.count("replay") == names.count("tape.stack") == 1
 

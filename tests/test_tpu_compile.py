@@ -99,3 +99,21 @@ def test_tape_anchor_program_compiles_for_v5e(one_chip):
               + 2 * sum(k for _, k in ed._SUM_BLOCKS))
     assert compiled.out_info.shape == (n_rows, 128)
     assert compiled.out_info.dtype == jnp.int32
+
+
+def test_scoring_program_compiles_for_v5e(one_chip):
+    """The exact scoring program at a 64-node job's stacked shape: the
+    most rows 1,048,480 requests over 64 shards can need, int64 as it
+    runs, with the sizes carried through the sort (no gather)."""
+
+    rows = (1_048_480 + 64 * (STREAM_LEN - 1)) // STREAM_LEN
+    from repro.core.random_factor import _stream_stats64
+
+    with x64():
+        x = jax.ShapeDtypeStruct((rows, STREAM_LEN), jnp.int64, sharding=one_chip)
+        compiled = _stream_stats64.lower(x, x).compile()
+    rf, pct, dist = compiled.out_info
+    assert rf.shape == pct.shape == dist.shape == (rows,)
+    assert (rf.dtype, pct.dtype, dist.dtype) == (jnp.int64, jnp.float64, jnp.int64)
+    hlo = compiled.as_text()
+    assert " sort(" in hlo and " gather(" not in hlo

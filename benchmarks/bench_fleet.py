@@ -3,8 +3,8 @@
 Part 1 — scoring: per-stream scalar NumPy (the seed simulator's hot path:
 one ``stream_percentage`` + one ``sorted_seek_distance`` per 128-request
 window inside a Python loop) versus the vectorized batched paths
-(``numpy`` int64 oracle, one-call ``jnp``, and the ``stream_rf`` Pallas
-kernel) on the same >= 4096-stream trace.  The acceptance bar is a >= 5x
+(``numpy`` int64 oracle and one-call exact ``jnp``) on the same
+>= 4096-stream trace.  The acceptance bar is a >= 5x
 speedup for batched over scalar.
 
 Part 2 — fleet scaling: aggregate throughput of the four schemes as the
@@ -17,7 +17,6 @@ from __future__ import annotations
 
 import time
 
-import jax
 import numpy as np
 
 from benchmarks.common import Row, timeit
@@ -65,13 +64,9 @@ def bench_scoring(rows: list[Row]) -> None:
     rows.append(Row("fleet_score_scalar", t_scalar * 1e6,
                     f"streams_per_s={sps:.0f}"))
 
-    # off the TPU the kernel runs in the Pallas interpreter, and its row
-    # says so (fleet_score_pallas-interpret)
-    interpret = jax.default_backend() != "tpu"
-    for backend in ("numpy", "jnp", "pallas"):
+    for backend in ("numpy", "jnp"):
         def score():
-            return compute_stream_scores(batch, STREAM_LEN, backend=backend,
-                                         interpret=interpret)
+            return compute_stream_scores(batch, STREAM_LEN, backend=backend)
 
         ran = score().backend  # warmup
         us, _ = timeit(score, repeat=3)

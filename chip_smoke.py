@@ -10,11 +10,7 @@ points a user calls (``compute_stream_scores``, ``FleetProgram.run``):
 1. device: platform, kind and count as JAX reports them.
 2. scoring: a ``--requests`` replay trace (64 KiB writes, offsets in
    ``[0, 2**38)``, 16 files, 8 apps, one 30 s compute gap) scored with
-   ``backend="jnp"`` must equal ``backend="numpy"`` bit for bit.  A trace
-   of the same seed and stream count with offsets below
-   ``2**31 - 64 KiB`` is scored by the compiled ``stream_stats`` kernel
-   (``backend="pallas"``, ``interpret=False``) and must equal numpy too;
-   the compiled program must hold the kernel (``tpu_custom_call``).
+   ``backend="jnp"`` must equal ``backend="numpy"`` bit for bit.
 3. replay: ``FleetProgram`` over ``--nodes`` x 4 schemes, range-offset
    sharding, ``jnp`` scoring, per-node capacity
    ``max(total_bytes // 2 // nodes, 64 MiB)``, once with ``ssd="constant"``
@@ -27,8 +23,7 @@ points a user calls (``compute_stream_scores``, ``FleetProgram.run``):
 Times printed along the way are information only.  On success the last
 line of stdout is ``{"ok": true, "device": {...}}``.  Any failed check or
 error exits non-zero without that line.  Without a TPU the script exits
-2 before any work, unless ``--rehearse`` is given: the CPU rehearsal runs
-the Pallas kernel in the interpreter and skips the compiled-kernel check.
+2 before any work, unless ``--rehearse`` is given for a CPU rehearsal.
 """
 
 from __future__ import annotations
@@ -54,7 +49,6 @@ from repro.core import (  # noqa: E402
 )
 from repro.core.engine_device import DEVICE_TOLERANCES  # noqa: E402
 from repro.core.workloads import MiB  # noqa: E402
-from repro.kernels.stream_rf.kernel import stream_stats  # noqa: E402
 from repro.runtime import use_compile_cache  # noqa: E402
 from repro.testing.golden import (  # noqa: E402
     diff_fleet,
@@ -97,34 +91,7 @@ def phase_scoring(args, batch) -> list[str]:
           f"steady {t_steady:.3f} s")
     if got.backend != "jnp":
         errors.append(f"jnp scoring ran as {got.backend!r}")
-    errors += _score_mismatches(got, want)
-
-    small = replay_trace(args.requests, seed=args.seed,
-                         offset_limit=2**31 - (64 << 10))
-    want = compute_stream_scores(small, STREAM_LEN, backend="numpy")
-    interpret = args.rehearse
-
-    def pallas():
-        return compute_stream_scores(small, STREAM_LEN, backend="pallas",
-                                     interpret=interpret)
-
-    got, t_first = _timed(pallas)
-    _, t_steady = _timed(pallas)
-    print(f"scoring {got.backend}: {len(got)} streams, first call "
-          f"{t_first:.3f} s, steady {t_steady:.3f} s")
-    if got.backend != ("pallas-interpret" if interpret else "pallas"):
-        errors.append(f"pallas scoring ran as {got.backend!r}")
-    errors += _score_mismatches(got, want)
-    if not interpret:
-        offs, szs, _ = small.padded_stream_matrix(STREAM_LEN)
-        hlo = stream_stats.lower(
-            offs.astype(np.int32), szs.astype(np.int32), interpret=False
-        ).compile().as_text()
-        if "tpu_custom_call" not in hlo:
-            errors.append("compiled stream_stats holds no tpu_custom_call")
-        else:
-            print("stream_stats compiled to a Mosaic kernel (tpu_custom_call)")
-    return errors
+    return errors + _score_mismatches(got, want)
 
 
 def _worst(expected: dict, actual: dict, field: str) -> str:

@@ -43,10 +43,8 @@ from .random_factor import (
     DEFAULT_STREAM_LEN,
     Request,
     StreamGrouper,
-    random_factor_batch,
     random_factor_sum,
     random_percentage,
-    random_percentage_batch,
     stream_percentage,
 )
 from .redirector import DataRedirector, Device, RoutedStream
@@ -90,8 +88,6 @@ __all__ = [
     "StreamGrouper",
     "random_factor_sum",
     "random_percentage",
-    "random_factor_batch",
-    "random_percentage_batch",
     "stream_percentage",
     "DataRedirector",
     "Device",

@@ -33,10 +33,14 @@ Structure (the state-struct / transition / orchestration split):
   draining.  All four schemes run the same step, selected by per-lane
   flags, so lanes of different schemes batch into one ``vmap``.
 * **orchestration** — :func:`replay_lanes` jits ``scan(vmap(step))`` plus
-  the vectorized end-of-trace drain and returns per-lane result arrays;
-  :func:`simulate_device` wraps a single lane into a
-  :class:`~repro.core.simulator.SimResult` (the ``engine="device"`` path
-  of :class:`IONodeSimulator`).
+  the vectorized end-of-trace drain and returns per-lane result arrays.
+  :func:`build_tapes` and :func:`replay_tapes` are the one way to it:
+  they hold the region-table rule, the scheme-major lane order and the
+  slot padding, and turn the lanes' arrays into
+  :class:`~repro.core.simulator.SimResult`.  Both
+  :class:`~repro.core.fleet.FleetProgram` (every ``scheme × node``
+  lane) and :func:`simulate_device` (one lane, the ``engine="device"``
+  path of :class:`IONodeSimulator`) call them.
 
 Dtype policy: the engine runs under the scoped 64-bit mode of
 :func:`repro.runtime.x64` — clocks/rates in float64, byte counters in
@@ -1692,7 +1696,8 @@ def replay_lanes(
 
 
 # ---------------------------------------------------------------------------
-# single-lane entry point (IONodeSimulator engine="device")
+# the lane set: the one way from shards to SimResults (FleetProgram and
+# IONodeSimulator engine="device")
 # ---------------------------------------------------------------------------
 
 
@@ -1705,6 +1710,110 @@ def per_app_bytes(batch) -> dict[int, int]:
     sums = np.zeros(len(apps), dtype=np.int64)
     np.add.at(sums, inverse, batch.sizes)
     return {int(a): int(s) for a, s in zip(apps, sums)}
+
+
+def build_tapes(
+    shards: Sequence,
+    scores: Sequence,
+    schemes: Sequence[str],
+    ssd_capacity: int,
+    hdd: HDDModel | None = None,
+    ssd: "SSDModel | object | None" = None,
+    link: IngestLink | None = None,
+    stream_len: int = DEFAULT_STREAM_LEN,
+    threshold_warmup: Sequence[float] | None = None,
+) -> list[dict[str, np.ndarray]]:
+    """One event tape per shard (:func:`build_events`), for lanes of
+    ``schemes``.
+
+    A tape carries the exact ``ssdup`` region table exactly when an
+    ``ssdup`` lane will replay it: the table is costed for that lane's
+    region bytes (half the SSD) and its watermark state after
+    ``threshold_warmup``.
+    """
+
+    region_cap = ssd_capacity // 2 if "ssdup" in schemes else None
+    static_rand = static_rand0(threshold_warmup)
+    return [
+        build_events(
+            batch, batch_scores, stream_len=stream_len, hdd=hdd, ssd=ssd,
+            link=link, region_cap=region_cap, static_rand=static_rand,
+        )
+        for batch, batch_scores in zip(shards, scores)
+    ]
+
+
+def replay_tapes(
+    tapes: Sequence[Mapping[str, np.ndarray]],
+    per_app: Sequence[dict[int, int]],
+    schemes: Sequence[str],
+    ssd_capacity: int,
+    hdd: HDDModel | None = None,
+    ssd: "SSDModel | object | None" = None,
+    interference: InterferenceModel | None = None,
+    flush_gate: float | str = 0.5,
+    adaptive_window: int = 64,
+    threshold_warmup: Sequence[float] | None = None,
+    sanitize: bool | None = None,
+) -> dict[str, tuple]:
+    """Replay N shards' tapes (:func:`build_tapes`) under every scheme
+    in one :func:`replay_lanes` call; returns each scheme's
+    :class:`~repro.core.simulator.SimResult` per node, in shard order
+    (``per_app`` holds each shard's :func:`per_app_bytes`).
+
+    Lane order is scheme-major: lane ``s * N + n`` replays shard ``n``
+    under scheme ``s``, and every scheme reuses the same N tapes.  The
+    region slots are padded to one count for all lanes, so lanes of
+    different schemes and shards share one compiled program.
+
+    Accuracy contract: every lane matches the batched numpy oracle to
+    the ``DEVICE_TOLERANCES`` tiers; the lanes it shares the call with do
+    not change its result.
+    """
+
+    from .simulator import SimResult  # deferred: simulator imports us lazily
+
+    n_nodes = len(tapes)
+    events = stack_events([t for _ in schemes for t in tapes])
+    with spans.span("fleet.lanes"):
+        slots = _pad_len(max(len(t["rg"]) for t in tapes))
+        lanes = _stack_lanes([
+            lane_consts(s, ssd_capacity, flush_gate, ssd=ssd,
+                        regions=t["rg"], slots=slots)
+            for s in schemes
+            for t in tapes
+        ])
+        state0 = _stack_lanes([
+            initial_lane_state(s, adaptive_window, threshold_warmup, ssd=ssd)
+            for s in schemes
+            for _ in tapes
+        ])
+    out = replay_lanes(events, lanes, state0, hdd=hdd,
+                       interference=interference, sanitize=sanitize)
+    with spans.span("fleet.assemble"):
+        results = {}
+        for si, scheme in enumerate(schemes):
+            nodes = []
+            for n in range(n_nodes):
+                i = si * n_nodes + n
+                b_ssd = int(out["bytes_to_ssd"][i])
+                b_hdd = int(out["bytes_to_hdd_direct"][i])
+                nodes.append(SimResult(
+                    scheme=scheme,
+                    io_seconds=float(out["io_seconds"][i]),
+                    total_seconds=float(out["total_seconds"][i]),
+                    total_bytes=b_ssd + b_hdd,
+                    bytes_to_ssd=b_ssd,
+                    bytes_to_hdd_direct=b_hdd,
+                    flushes=int(out["flushes"][i]),
+                    flush_paused_seconds=float(out["flush_paused_seconds"][i]),
+                    blocked_seconds=float(out["blocked_seconds"][i]),
+                    peak_ssd_occupancy=int(out["peak_ssd_occupancy"][i]),
+                    metadata_bytes=0,
+                    per_app_bytes=per_app[n],
+                ))
+            results[scheme] = tuple(nodes)
+        return results
 
 
 def simulate_device(
@@ -1726,37 +1835,14 @@ def simulate_device(
     :class:`~repro.core.simulator.SimResult` (see the module docstring
     for the accuracy contract vs the numpy engines)."""
 
-    from .simulator import SimResult  # deferred: simulator imports us lazily
-
-    tape = build_events(
-        batch, scores, stream_len=stream_len, hdd=hdd, ssd=ssd, link=link,
-        region_cap=ssd_capacity // 2 if scheme == "ssdup" else None,
-        static_rand=static_rand0(threshold_warmup),
+    tapes = build_tapes(
+        [batch], [scores], (scheme,), ssd_capacity, hdd=hdd, ssd=ssd,
+        link=link, stream_len=stream_len, threshold_warmup=threshold_warmup,
     )
-    events = stack_events([tape])
-    lanes = _stack_lanes([
-        lane_consts(scheme, ssd_capacity, flush_gate, ssd=ssd, regions=tape["rg"],
-                    slots=_pad_len(len(tape["rg"])))
-    ])
-    state0 = _stack_lanes(
-        [initial_lane_state(scheme, adaptive_window, threshold_warmup,
-                            ssd=ssd)]
-    )
-    out = replay_lanes(events, lanes, state0, hdd=hdd,
-                       interference=interference, sanitize=sanitize)
-    b_ssd = int(out["bytes_to_ssd"][0])
-    b_hdd = int(out["bytes_to_hdd_direct"][0])
-    return SimResult(
-        scheme=scheme,
-        io_seconds=float(out["io_seconds"][0]),
-        total_seconds=float(out["total_seconds"][0]),
-        total_bytes=b_ssd + b_hdd,
-        bytes_to_ssd=b_ssd,
-        bytes_to_hdd_direct=b_hdd,
-        flushes=int(out["flushes"][0]),
-        flush_paused_seconds=float(out["flush_paused_seconds"][0]),
-        blocked_seconds=float(out["blocked_seconds"][0]),
-        peak_ssd_occupancy=int(out["peak_ssd_occupancy"][0]),
-        metadata_bytes=0,
-        per_app_bytes=per_app_bytes(batch),
-    )
+    (result,) = replay_tapes(
+        tapes, [per_app_bytes(batch)], (scheme,), ssd_capacity, hdd=hdd,
+        ssd=ssd, interference=interference, flush_gate=flush_gate,
+        adaptive_window=adaptive_window, threshold_warmup=threshold_warmup,
+        sanitize=sanitize,
+    )[scheme]
+    return result

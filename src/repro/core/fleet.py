@@ -125,7 +125,7 @@ class FleetSimulator:
         :data:`repro.distributed.sharding.TRACE_POLICIES`.
     score_backend:
         Backend for the up-front batched stream scoring: ``"numpy"``
-        (exact, default), ``"jnp"``, or ``"pallas"``.
+        (exact, default) or ``"jnp"`` (exact, on the device).
     threshold_scope:
         ``"node"`` (default): every node's detector starts cold and only
         ever observes its own shard's streams — the deployment where each
@@ -267,11 +267,13 @@ class FleetProgram:
 
     Where :class:`FleetSimulator` loops Python over nodes (and callers
     loop over schemes), ``FleetProgram`` lowers every shard to an event
-    tape ONCE (tapes are scheme-independent), stacks one lane per
+    tape ONCE (every scheme replays the same tapes), stacks one lane per
     ``scheme × node`` combination, and replays all of them in a single
-    ``jit(scan(vmap(step)))`` device call through
-    :mod:`repro.core.engine_device`.  A 64-node × 4-scheme sweep is one
-    XLA executable launch instead of 256 Python replays.
+    ``jit(scan(vmap(step)))`` device call through the lane builder of
+    :mod:`repro.core.engine_device` (:func:`~repro.core.engine_device.build_tapes`,
+    :func:`~repro.core.engine_device.replay_tapes`), the path
+    ``engine="device"`` takes for one lane.  A 64-node × 4-scheme sweep
+    is one XLA executable launch instead of 256 Python replays.
 
     Results carry the device engine's documented tolerances
     (:data:`repro.core.engine_device.DEVICE_TOLERANCES`) vs the numpy
@@ -339,8 +341,8 @@ class FleetProgram:
         self.adaptive_window = adaptive_window
         self.threshold_warmup = threshold_warmup
         self._ed = engine_device
-        # tapes are scheme-independent and pure functions of the trace:
-        # repeat sweeps of the same TraceBatch (parameter studies, the
+        # every scheme replays the same tapes, pure functions of the
+        # trace: repeat sweeps of the same TraceBatch (parameter studies, the
         # steady-state benchmark) reuse them instead of re-sharding and
         # re-scoring. Keyed by object identity with a liveness anchor so
         # a recycled id can never alias a different trace.
@@ -365,9 +367,10 @@ class FleetProgram:
         ``DEVICE_TOLERANCES`` tiers against the batched numpy oracle.
         """
 
-        # the layer entries (self.shard, compute_stream_scores,
-        # ed.build_events, ed.stack_events, ed.replay_lanes) are looked
-        # up at call time, so that a caller can wrap them
+        # the layer entries (self.shard, compute_stream_scores, and the
+        # build_events, stack_events and replay_lanes that build_tapes and
+        # replay_tapes call) are looked up at call time, so that a caller
+        # can wrap them
         ed = self._ed
         batch = (
             trace if isinstance(trace, TraceBatch)
@@ -379,85 +382,33 @@ class FleetProgram:
         else:
             shards = self.shard(batch)
             # every shard's streams in one scoring call, one device
-            # dispatch on the device backends
+            # dispatch on the device backend
             job = TraceShards(tuple(shards))
             scores = compute_stream_scores(
                 job, self.stream_len, backend=self.score_backend
             ).split(job.stream_counts(self.stream_len))
-            # ssdup lanes cost each region's flush from its exact table
-            region_cap = (self.ssd_capacity // 2 if "ssdup" in self.schemes
-                          else None)
-            tapes = [
-                ed.build_events(
-                    shard, shard_scores,
-                    stream_len=self.stream_len,
-                    hdd=self.hdd, ssd=self.ssd, link=self.link,
-                    region_cap=region_cap,
-                    static_rand=ed.static_rand0(self.threshold_warmup),
-                )
-                for shard, shard_scores in zip(shards, scores)
-            ]
+            tapes = ed.build_tapes(
+                shards, scores, self.schemes, self.ssd_capacity,
+                hdd=self.hdd, ssd=self.ssd, link=self.link,
+                stream_len=self.stream_len,
+                threshold_warmup=self.threshold_warmup,
+            )
             with spans.span("fleet.lanes"):
                 per_app = [ed.per_app_bytes(shard) for shard in shards]
             self._tape_cache = (id(batch), batch, shards, tapes, per_app)
-        # lane order is scheme-major: lane s * N + n replays shard n
-        # under scheme s (every scheme reuses the same N tapes)
-        events = ed.stack_events(
-            [tapes[n] for _ in self.schemes for n in range(self.num_nodes)]
+        nodes = ed.replay_tapes(
+            tapes, per_app, self.schemes, self.ssd_capacity,
+            hdd=self.hdd, ssd=self.ssd, interference=self.interference,
+            flush_gate=self.flush_gate, adaptive_window=self.adaptive_window,
+            threshold_warmup=self.threshold_warmup,
         )
-        with spans.span("fleet.lanes"):
-            slots = ed._pad_len(max(len(t["rg"]) for t in tapes))
-            lanes = ed._stack_lanes([
-                ed.lane_consts(
-                    s, self.ssd_capacity, self.flush_gate, ssd=self.ssd,
-                    regions=tapes[n]["rg"], slots=slots,
-                )
-                for s in self.schemes
-                for n in range(self.num_nodes)
-            ])
-            state0 = ed._stack_lanes([
-                ed.initial_lane_state(
-                    s, self.adaptive_window, self.threshold_warmup,
-                    ssd=self.ssd,
-                )
-                for s in self.schemes
-                for _ in range(self.num_nodes)
-            ])
-        out = ed.replay_lanes(
-            events, lanes, state0,
-            hdd=self.hdd, interference=self.interference,
-        )
-        with spans.span("fleet.assemble"):
-            results: dict[str, FleetResult] = {}
-            for si, scheme in enumerate(self.schemes):
-                nodes = []
-                for n in range(self.num_nodes):
-                    i = si * self.num_nodes + n
-                    b_ssd = int(out["bytes_to_ssd"][i])
-                    b_hdd = int(out["bytes_to_hdd_direct"][i])
-                    nodes.append(SimResult(
-                        scheme=scheme,
-                        io_seconds=float(out["io_seconds"][i]),
-                        total_seconds=float(out["total_seconds"][i]),
-                        total_bytes=b_ssd + b_hdd,
-                        bytes_to_ssd=b_ssd,
-                        bytes_to_hdd_direct=b_hdd,
-                        flushes=int(out["flushes"][i]),
-                        flush_paused_seconds=float(
-                            out["flush_paused_seconds"][i]
-                        ),
-                        blocked_seconds=float(out["blocked_seconds"][i]),
-                        peak_ssd_occupancy=int(out["peak_ssd_occupancy"][i]),
-                        metadata_bytes=0,
-                        per_app_bytes=per_app[n],
-                    ))
-                results[scheme] = FleetResult(
-                    scheme=scheme,
-                    policy=self.policy,
-                    num_nodes=self.num_nodes,
-                    node_results=tuple(nodes),
-                )
-            return results
+        return {
+            scheme: FleetResult(
+                scheme=scheme, policy=self.policy,
+                num_nodes=self.num_nodes, node_results=results,
+            )
+            for scheme, results in nodes.items()
+        }
 
 
 def run_fleet_schemes(

@@ -15,13 +15,15 @@ The detector works purely on request *metadata* (offset, size, file, app) —
 it never touches payload bytes, which is why it is cheap enough to run on the
 server side for every stream (paper Table 1 measures <1% overhead).
 
-Two implementations live here:
+Three forms of the one statistic live here:
 
-* a scalar/NumPy path used by the host-side control plane
-  (:class:`StreamGrouper`, :func:`random_factor_sum`), and
-* a batched ``jnp`` path (:func:`random_factor_batch`) that scores many
-  streams at once; it is also the oracle for the Pallas kernel in
-  ``repro.kernels.stream_rf``.
+* the scalar path of the host-side control plane and the per-request
+  oracle (:class:`StreamGrouper`, :func:`random_factor_sum`,
+  :func:`sorted_seek_distance`);
+* the batched numpy oracle (:func:`stream_stats_batch_np`), which scores
+  many streams at once on the host; and
+* the exact int64 device program (:func:`stream_stats_batch64`), bit-equal
+  to that oracle.
 """
 
 from __future__ import annotations
@@ -95,84 +97,18 @@ def random_percentage(
     return random_factor_sum(offs, sizes) / (n - 1)
 
 
-def random_factor_batch(offsets, sizes):
-    """Batched random factor: ``(M, N) -> (M,)`` on device.
-
-    jnp oracle shared with the ``stream_rf`` Pallas kernel.  Sorting uses
-    ``jnp.sort``; the seek count compares sorted-adjacent gaps against the
-    size carried by the *lower-offset* request of each pair (requests are
-    sorted together with their sizes).
-    """
-
-    offs = jnp.asarray(offsets, dtype=jnp.int32)
-    szs = jnp.broadcast_to(jnp.asarray(sizes, dtype=jnp.int32), offs.shape)
-    order = jnp.argsort(offs, axis=-1, stable=True)
-    so = jnp.take_along_axis(offs, order, axis=-1)
-    ss = jnp.take_along_axis(szs, order, axis=-1)
-    gaps = so[..., 1:] - so[..., :-1]
-    return jnp.sum((gaps != ss[..., :-1]).astype(jnp.int32), axis=-1)
-
-
-def random_percentage_batch(offsets, sizes):
-    """Batched ``S/(N-1)`` with float32 output."""
-
-    offs = jnp.asarray(offsets)
-    n = offs.shape[-1]
-    s = random_factor_batch(offs, sizes)
-    return s.astype(jnp.float32) / max(n - 1, 1)
-
-
-def seek_distance_batch(offsets, sizes):
-    """Batched sorted seek distance: ``(M, N) -> (M,)`` on device.
-
-    Same definition as :func:`sorted_seek_distance` — total |gap - size|
-    over sorted-adjacent pairs; see :func:`stream_stats_batch` for the
-    dtype caveats.
-    """
-
-    return stream_stats_batch(offsets, sizes)[2]
-
-
-def stream_stats_batch(offsets, sizes):
-    """All three per-stream statistics in one device call.
-
-    ``(M, N)`` offsets/sizes -> ``(rf_sum (M,), percentage (M,),
-    seek_distance (M,))``.  One sort feeds both the Eq. 1 seek count and
-    the seek-distance aggregate; this is the jnp oracle for the
-    ``stream_rf`` Pallas kernel and the device fast path behind
-    :func:`repro.core.trace.compute_stream_scores`.
-
-    Dtypes: offsets/sizes ride int32 lanes (jax's default integer width
-    here), so per-request values must fit below 2 GiB; the seek-distance
-    *sum* can exceed int32 even then (127 residuals of up to 2 GiB), so
-    it is accumulated in float32 — overflow-safe, with ~1e-7 relative
-    rounding above 16 MiB totals (irrelevant to the timing model, which
-    multiplies by seconds-per-byte).  The host path
-    (:func:`stream_stats_batch_np`) is the full-range int64 exact oracle.
-    """
-
-    offs = jnp.asarray(offsets, dtype=jnp.int32)
-    szs = jnp.broadcast_to(jnp.asarray(sizes, dtype=jnp.int32), offs.shape)
-    n = offs.shape[-1]
-    order = jnp.argsort(offs, axis=-1, stable=True)
-    so = jnp.take_along_axis(offs, order, axis=-1)
-    ss = jnp.take_along_axis(szs, order, axis=-1)
-    resid = so[..., 1:] - so[..., :-1] - ss[..., :-1]
-    rf = jnp.sum((resid != 0).astype(jnp.int32), axis=-1)
-    pct = rf.astype(jnp.float32) / max(n - 1, 1)
-    dist = jnp.sum(jnp.abs(resid).astype(jnp.float32), axis=-1)
-    return rf, pct, dist
-
-
 def stream_stats_batch64(offsets, sizes):
-    """Exact int64/float64 device scoring — bit-equal to the numpy oracle.
+    """Exact int64 device scoring of many streams at once.
 
-    Same math as :func:`stream_stats_batch`, run under the scoped
-    :func:`repro.runtime.x64` so offsets/sizes ride true int64 lanes
-    and the percentage divides in float64.  This removes BOTH device-dtype
-    caveats: offsets above 2 GiB no longer truncate, and the seek-distance
-    sum accumulates as int64 with no float32 rounding.  ``(M, N)`` ->
-    ``(rf int64, percentage float64, seek_distance int64)``.
+    Same math as :func:`stream_stats_batch_np`, run under the scoped
+    :func:`repro.runtime.x64` so offsets/sizes ride true int64 lanes:
+    offsets of any magnitude stay exact, and the seek-distance sum
+    accumulates as int64.  ``(M, N)`` -> ``(rf int64, percentage
+    float64, seek_distance int64)``.  The seek counts and distances are
+    bit-equal to the oracle's; the percentage, divided by a constant in
+    the compiled program, may differ from the oracle's by one float64
+    ulp, so :func:`repro.core.trace.compute_stream_scores` divides the
+    counts on the host instead.
 
     The scope is per-call: the global jax x64 flag is untouched, so f32
     kernels elsewhere in the process are unaffected.  The math is one
@@ -213,8 +149,8 @@ def stream_stats_batch_np(offsets, sizes):
     int64)``, each ``(M,)``.  Bit-for-bit equal to looping the scalar
     :func:`random_factor_sum` / :func:`random_percentage` /
     :func:`sorted_seek_distance` over the rows — the fleet simulator's
-    default scoring path and the correctness oracle for the device
-    backends.
+    default scoring path and the correctness oracle for
+    :func:`stream_stats_batch64`.
     """
 
     offs = np.asarray(offsets, dtype=np.int64)

@@ -18,23 +18,20 @@ fleet layer (:mod:`repro.core.fleet`):
   precomputed for *all* streams of a trace in one vectorized call so
   :meth:`repro.core.simulator.IONodeSimulator.run` never re-sorts a stream
   in its hot loop.
-* :func:`compute_stream_scores` — scoring entry point with three backends:
+* :func:`compute_stream_scores` — scoring entry point with two backends:
   ``numpy`` (vectorized ``int64`` host math, bit-exact against the scalar
-  definitions — the default and the oracle), ``jnp`` (one device call via
-  :func:`repro.core.random_factor.stream_stats_batch64` under a scoped
+  definitions — the default and the oracle) and ``jnp`` (one device call
+  via :func:`repro.core.random_factor.stream_stats_batch64` under a scoped
   x64 enable — int64 lanes, float64 division, bit-exact at any offset
-  magnitude), and ``pallas`` (the fused ``repro.kernels.stream_rf``
-  TPU kernel; int32 lanes, so a trace with offsets or sizes beyond
-  ``2**31 - 1`` is refused with a ``ValueError``; exact below that).
-  No backend falls back to another: :attr:`StreamScores.backend` names
-  the path that ran.
+  magnitude).  Neither falls back to the other:
+  :attr:`StreamScores.backend` names the path that ran.
 
 Stream grouping follows :class:`repro.core.random_factor.StreamGrouper`
 semantics exactly: requests are blocked in arrival order into windows of
 ``stream_len``; gaps do NOT flush a partial window.  The trailing partial
 stream is padded into a score-neutral fixed-shape row
 (:meth:`TraceBatch.padded_stream_matrix`) so every backend scores it in
-the same pass as the full windows, a device backend in the same dispatch.
+the same pass as the full windows, the device backend in the same dispatch.
 """
 
 from __future__ import annotations
@@ -388,7 +385,7 @@ class StreamScores:                             # __eq__ would raise
     nbytes: np.ndarray  # (S,) int64
     offset_sum: np.ndarray  # (S,) int64
     stream_len: int
-    backend: str  # the path that ran: numpy, jnp, pallas, pallas-interpret
+    backend: str  # the path that ran: numpy or jnp
 
     def __len__(self) -> int:
         return int(self.rf_sum.shape[0])
@@ -427,40 +424,7 @@ class StreamScores:                             # __eq__ would raise
         ]
 
 
-SCORE_BACKENDS = ("numpy", "jnp", "pallas")
-
-
-_INT32_MAX = np.int64(2**31 - 1)
-
-
-def _score_streams_device(
-    offs2d: np.ndarray, szs2d: np.ndarray, backend: str, interpret: bool,
-) -> tuple[np.ndarray, np.ndarray]:
-    """Seek counts and seek distances ``(rf, dist)`` of the padded
-    (S, L) stream matrix, scored on device in one dispatch.
-
-    ``jnp`` runs :func:`repro.core.random_factor.stream_stats_batch64` in
-    the scoped 64-bit mode — int64 lanes, exact at any offset magnitude.
-    ``pallas`` runs the fused kernel on int32 lanes, exact there; offsets
-    or sizes beyond int32 would TRUNCATE into wrong seek counts, so such a
-    trace is refused.
-    """
-
-    if backend == "pallas":
-        if (np.abs(offs2d).max(initial=0) > _INT32_MAX
-                or szs2d.max(initial=0) > _INT32_MAX):
-            raise ValueError(
-                "backend='pallas' scores on int32 lanes and this trace has "
-                "offsets or sizes beyond 2**31 - 1; backend='jnp' is the "
-                "exact device backend for it (int64 lanes)"
-            )
-        from repro.kernels.stream_rf.ops import stream_stats_op
-
-        rf, dist = stream_stats_op(offs2d, szs2d, interpret=interpret)
-    else:
-        rf, _, dist = stream_stats_batch64(offs2d, szs2d)
-    with spans.span("score.readback"):
-        return np.asarray(rf, dtype=np.int64), np.asarray(dist, dtype=np.int64)
+SCORE_BACKENDS = ("numpy", "jnp")
 
 
 @spans.spanned("score")
@@ -468,7 +432,6 @@ def compute_stream_scores(
     trace: "TraceBatch | TraceShards | Sequence[TraceItem]",
     stream_len: int = DEFAULT_STREAM_LEN,
     backend: str = "numpy",
-    interpret: bool = False,
 ) -> StreamScores:
     """Score every stream of a trace in one vectorized pass.
 
@@ -479,16 +442,10 @@ def compute_stream_scores(
     :meth:`TraceBatch.padded_stream_matrix`, trailing partial included
     through its score-neutral padding.  ``backend="numpy"`` (default)
     needs no accelerator.  ``"jnp"`` runs every stream as ONE device
-    call in the scoped 64-bit mode.  ``"pallas"`` routes the same padded
-    matrix through the fused ``stream_rf`` bitonic-sort kernel (int32
-    lanes: requires power-of-two ``stream_len``, and raises
-    ``ValueError`` on offsets or sizes beyond ``2**31 - 1``).  The kernel
-    is compiled for the TPU unless ``interpret=True`` runs it in the
-    Pallas interpreter; the result's ``backend`` is then
-    ``"pallas-interpret"``.
+    call in the scoped 64-bit mode.
 
     Given :class:`TraceShards`, the shards' padded rows are stacked into
-    one matrix, which the device backends score in one dispatch; the
+    one matrix, which the device backend scores in one dispatch; the
     result covers the shards in order, and :meth:`StreamScores.split`
     cuts it back into one per shard.
     """
@@ -503,7 +460,10 @@ def compute_stream_scores(
     if backend == "numpy":
         rf, _, dist = stream_stats_batch_np(offs_p, szs_p)
     elif offs_p.shape[0]:
-        rf, dist = _score_streams_device(offs_p, szs_p, backend, interpret)
+        rf, _, dist = stream_stats_batch64(offs_p, szs_p)
+        with spans.span("score.readback"):
+            rf = np.asarray(rf, dtype=np.int64)
+            dist = np.asarray(dist, dtype=np.int64)
     else:
         rf = dist = np.zeros(0, dtype=np.int64)
     # rows past the streams are the matrix's neutral padding; the
@@ -520,8 +480,5 @@ def compute_stream_scores(
         nbytes=np.asarray(nbytes, dtype=np.int64),
         offset_sum=np.asarray(osum, dtype=np.int64),
         stream_len=stream_len,
-        backend=(
-            "pallas-interpret" if backend == "pallas" and interpret
-            else backend
-        ),
+        backend=backend,
     )

@@ -85,18 +85,17 @@ def golden_trace(name: str) -> TraceBatch:
     return build()
 
 
-def replay_trace(n_requests: int, seed: int = 0,
-                 offset_limit: int = 1 << 38) -> TraceBatch:
+def replay_trace(n_requests: int, seed: int = 0) -> TraceBatch:
     """The replay benchmarks' random-heavy multi-app trace.
 
-    64 KiB writes at uniform offsets in ``[0, offset_limit)`` over 16
+    64 KiB writes at uniform offsets in ``[0, 2**38)`` over 16
     files from 8 apps, with one 30 s compute gap halfway through; drawn
     in bulk from ``seed``.
     """
 
     rng = np.random.default_rng(seed)
     return TraceBatch(
-        offsets=rng.integers(0, offset_limit, size=n_requests).astype(np.int64),
+        offsets=rng.integers(0, 1 << 38, size=n_requests).astype(np.int64),
         sizes=np.full(n_requests, 64 << 10, dtype=np.int64),
         file_ids=rng.integers(0, 16, size=n_requests).astype(np.int64),
         app_ids=rng.integers(0, 8, size=n_requests).astype(np.int64),

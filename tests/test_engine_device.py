@@ -179,26 +179,37 @@ def test_fleet_program_matches_fixture_matrix(payloads):
         assert diffs == [], f"FleetProgram {scheme}:\n" + "\n".join(diffs)
 
 
-def test_fleet_program_equals_per_lane_device_dispatch():
+@pytest.fixture(scope="module")
+def swept():
+    """One fused sweep of every scheme over the golden trace's shards,
+    per storage backend."""
+
+    batch = golden_trace("strided-gaps")
+    cap = golden._node_capacity(batch.total_bytes)
+    return batch, cap, {
+        ssd: FleetProgram(num_nodes=golden.FIXTURE_NODES, schemes=golden.FIXTURE_SCHEMES,
+                          policy="round-robin-app", ssd_capacity=cap,
+                          ssd=ssd).run(batch)
+        for ssd in ("constant", "ftl")
+    }
+
+
+@pytest.mark.parametrize("ssd", ["constant", "ftl"])
+@pytest.mark.parametrize("scheme", golden.FIXTURE_SCHEMES)
+def test_fleet_program_equals_per_lane_device_dispatch(swept, scheme, ssd):
     """Batching lanes must not change a single number: the fused sweep
     and N independent ``engine="device"`` runs share one code path, so
     they agree to f64 bit-level on every field."""
 
     from repro.core import FleetSimulator
 
-    batch = golden_trace("strided-gaps")
-    cap = golden._node_capacity(batch.total_bytes)
-    prog = FleetProgram(num_nodes=golden.FIXTURE_NODES,
-                        schemes=("ssdup", "ssdup+"),
-                        policy="round-robin-app", ssd_capacity=cap)
-    swept = prog.run(batch)
-    for scheme in ("ssdup", "ssdup+"):
-        loop = FleetSimulator(num_nodes=golden.FIXTURE_NODES, scheme=scheme,
-                              policy="round-robin-app", ssd_capacity=cap,
-                              engine="device").run(batch)
-        a = fleet_result_to_dict(swept[scheme])
-        b = fleet_result_to_dict(loop)
-        assert a == b, f"{scheme}: fused sweep != per-lane device replay"
+    batch, cap, sweeps = swept
+    loop = FleetSimulator(num_nodes=golden.FIXTURE_NODES, scheme=scheme,
+                          policy="round-robin-app", ssd_capacity=cap,
+                          ssd=ssd, engine="device").run(batch)
+    a = fleet_result_to_dict(sweeps[ssd][scheme])
+    b = fleet_result_to_dict(loop)
+    assert a == b, f"{scheme}/{ssd}: fused sweep != per-lane device replay"
 
 
 def test_plain_bb_cross_stream_merge_routing():

@@ -1,69 +1,97 @@
-"""Pallas kernel sweeps vs. pure-jnp oracles (interpret mode on CPU).
+"""Device programs vs. their oracles: the exact stream-scoring program
+against the numpy oracle, and the Pallas flash-attention kernel against
+its pure-jnp reference (interpret mode on CPU).
 
-Per the assignment: for each kernel, sweep shapes/dtypes and
-assert_allclose against ref.py.
+For each, sweep shapes/dtypes and compare against the oracle.
 """
 
 import numpy as np
 import pytest
-import jax
 import jax.numpy as jnp
 
-from repro.core.random_factor import random_factor_batch
+from repro.core.random_factor import (
+    random_factor_sum,
+    random_percentage,
+    seek_distance_np,
+    stream_stats_batch64,
+    stream_stats_batch_np,
+)
 from repro.kernels.flash_attention.ops import flash_attention_op
 from repro.kernels.flash_attention.ref import flash_attention_ref
-from repro.kernels.stream_rf.ops import random_percentage_op, stream_rf_op
-from repro.kernels.stream_rf.ref import stream_rf_ref
 
 pytestmark = pytest.mark.slow  # interpret-mode Pallas runs, seconds per case
 
 
+def _assert_bit_equal(offs, szs):
+    """Seek counts and distances bit-equal to the oracle; the percentage,
+    which the program divides by a constant, within one float64 ulp."""
+
+    got = [np.asarray(x) for x in stream_stats_batch64(offs, szs)]
+    rf, pct, dist = stream_stats_batch_np(offs, szs)
+    for name, g, w in zip(("rf", "pct", "dist"), got, (rf, pct, dist)):
+        assert g.dtype == w.dtype, name
+    np.testing.assert_array_equal(got[0], rf, err_msg="rf")
+    np.testing.assert_array_equal(got[2], dist, err_msg="dist")
+    np.testing.assert_array_max_ulp(got[1], pct, maxulp=1)
+    return got
+
+
 class TestStreamRF:
+    """``stream_stats_batch64``, the scoring program the device backend
+    runs, is bit-equal to ``stream_stats_batch_np``."""
+
     @pytest.mark.parametrize("m", [1, 3, 8, 37, 300])
     @pytest.mark.parametrize("n", [8, 64, 128])
     def test_shapes_vs_ref(self, m, n):
         rng = np.random.default_rng(m * 1000 + n)
-        offs = rng.integers(0, 1 << 24, size=(m, n)).astype(np.int32)
-        szs = rng.integers(1, 1 << 10, size=(m, n)).astype(np.int32)
-        got = np.asarray(stream_rf_op(offs, szs, interpret=True))
-        want = np.asarray(stream_rf_ref(offs, szs))
-        np.testing.assert_array_equal(got, want)
+        # a grid of 4 KiB slots above 2**40: merges, seeks, overlaps and
+        # tied offsets of different sizes all occur
+        offs = (1 << 40) + rng.integers(0, 2 * n, size=(m, n)) * 4096
+        szs = rng.choice([4096, 8192, 12288], size=(m, n))
+        _assert_bit_equal(offs, szs)
 
     def test_agrees_with_core_detector(self):
-        """Kernel == the host control-plane's batched scorer (same Eq. 1)."""
+        """The program == the scalar per-request definitions, row by row."""
 
         rng = np.random.default_rng(7)
-        offs = rng.integers(0, 1 << 20, size=(16, 128)).astype(np.int32)
-        szs = np.full((16, 128), 256, np.int32)
-        got = np.asarray(stream_rf_op(offs, szs, interpret=True))
-        want = np.asarray(random_factor_batch(offs, szs))
-        np.testing.assert_array_equal(got, want)
+        offs = rng.integers(0, 1 << 20, size=(16, 128)) * 256
+        szs = np.full((16, 128), 256, np.int64)
+        rf, pct, dist = _assert_bit_equal(offs, szs)
+        for i in range(16):
+            assert rf[i] == random_factor_sum(offs[i], szs[i])
+            assert pct[i] == random_percentage(offs[i], szs[i])
+            assert dist[i] == seek_distance_np(offs[i], szs[i])
 
     def test_contiguous_and_reversed(self):
-        offs = (np.arange(128, dtype=np.int32) * 64)[None]
-        szs = np.full((1, 128), 64, np.int32)
-        assert int(stream_rf_op(offs, szs, interpret=True)[0]) == 0
-        assert int(stream_rf_op(offs[:, ::-1].copy(), szs, interpret=True)[0]) == 0  # sorted away
+        offs = (np.arange(128, dtype=np.int64) * 64)[None]
+        szs = np.full((1, 128), 64, np.int64)
+        assert int(_assert_bit_equal(offs, szs)[0][0]) == 0
+        assert int(_assert_bit_equal(offs[:, ::-1].copy(), szs)[0][0]) == 0  # sorted away
 
     def test_fully_random(self):
-        offs = (np.arange(128, dtype=np.int32) * 1000)[None]
-        szs = np.full((1, 128), 64, np.int32)
-        assert int(stream_rf_op(offs, szs, interpret=True)[0]) == 127
+        offs = (np.arange(128, dtype=np.int64) * 1000)[None]
+        szs = np.full((1, 128), 64, np.int64)
+        assert int(_assert_bit_equal(offs, szs)[0][0]) == 127
 
     def test_percentage(self):
-        offs = (np.arange(128, dtype=np.int32) * 1000)[None]
-        szs = np.full((1, 128), 64, np.int32)
-        assert float(random_percentage_op(offs, szs, interpret=True)[0]) == pytest.approx(1.0)
+        offs = (np.arange(128, dtype=np.int64) * 1000)[None]
+        szs = np.full((1, 128), 64, np.int64)
+        assert float(_assert_bit_equal(offs, szs)[1][0]) == 1.0
 
     def test_block_boundary_padding(self):
-        """M not divisible by the stream block: padded rows must not leak."""
+        """All-zero rows appended to a matrix (the neutral rows that round
+        a job's stacked matrix up to its fixed shape) score nothing and
+        leave the real rows' scores as they are."""
 
         rng = np.random.default_rng(9)
-        offs = rng.integers(0, 1 << 20, size=(5, 128)).astype(np.int32)
-        szs = np.full((5, 128), 17, np.int32)
-        got = np.asarray(stream_rf_op(offs, szs, interpret=True, block_streams=4))
-        want = np.asarray(stream_rf_ref(offs, szs))
-        np.testing.assert_array_equal(got, want)
+        offs = rng.integers(0, 1 << 20, size=(5, 128)) * 17
+        szs = np.full((5, 128), 17, np.int64)
+        alone = _assert_bit_equal(offs, szs)
+        zeros = np.zeros((3, 128), np.int64)
+        padded = _assert_bit_equal(np.vstack([offs, zeros]), np.vstack([szs, zeros]))
+        for a, p in zip(alone, padded):
+            np.testing.assert_array_equal(p[:5], a)
+            assert not p[5:].any()
 
 
 class TestFlashAttention:

@@ -12,12 +12,11 @@ except ImportError:  # deterministic no-shrink fallback, same API surface
 from repro.core import (
     Request,
     StreamGrouper,
-    random_factor_batch,
     random_factor_sum,
     random_percentage,
-    random_percentage_batch,
     stream_percentage,
 )
+from repro.core.random_factor import stream_stats_batch64, stream_stats_batch_np
 
 REQ = 256 * 1024
 
@@ -66,23 +65,25 @@ class TestRandomFactorScalar:
 
 
 class TestBatchOracleAgreement:
-    """The jnp batch path must agree with the scalar path (it is also the
-    oracle for the stream_rf Pallas kernel)."""
+    """The exact device program must agree with the numpy batch oracle
+    bit for bit, and both with the scalar path."""
 
     @pytest.mark.parametrize("n", [2, 16, 128, 256])
     def test_agreement_random(self, n):
         rng = np.random.default_rng(n)
-        offs = rng.integers(0, 1 << 20, size=(8, n)).astype(np.int32)
-        sizes = np.full((8, n), 7, np.int32)
-        batch = np.asarray(random_factor_batch(offs, sizes))
+        offs = rng.integers(0, 1 << 20, size=(8, n)) * 7
+        sizes = np.full((8, n), 7, np.int64)
+        batch = np.asarray(stream_stats_batch64(offs, sizes)[0])
+        np.testing.assert_array_equal(batch, stream_stats_batch_np(offs, sizes)[0])
         for i in range(8):
             assert batch[i] == random_factor_sum(offs[i], sizes[i])
 
     def test_percentage_batch(self):
-        offs = np.arange(128, dtype=np.int32)[None, :] * 7
-        out = np.asarray(random_percentage_batch(offs, 7))
+        offs = np.arange(128, dtype=np.int64)[None, :] * 7
+        out = np.asarray(stream_stats_batch64(offs, 7)[1])
         assert out.shape == (1,)
-        assert out[0] == pytest.approx(0.0)
+        assert out[0] == 0.0
+        np.testing.assert_array_equal(out, stream_stats_batch_np(offs, 7)[1])
 
 
 @settings(max_examples=200, deadline=None)

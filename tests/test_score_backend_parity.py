@@ -1,23 +1,28 @@
-"""Scoring-backend parity: ``jnp`` and ``pallas`` vs the numpy oracle.
+"""Scoring-backend parity: ``jnp`` vs the numpy oracle.
 
-``compute_stream_scores`` has three backends; the numpy path is the
+``compute_stream_scores`` has two backends; the numpy path is the
 int64 bit-exact oracle.  The ``jnp`` backend runs in the scoped 64-bit
 mode (int64 lanes, float64 division) and must be BIT-EXACT on every
-field at any offset magnitude.  The ``pallas`` backend keeps the fused
-kernel's int32 lanes and is bit-exact inside them (here in the Pallas
-interpreter; ``chip_smoke.py`` runs it compiled on the TPU).  Both
-backends score the trailing partial stream on device via the
-score-neutral padded row (``TraceBatch.padded_stream_matrix``), and a
-trace whose offsets overflow the kernel's int32 lanes is refused, never
-scored elsewhere.
+field at any offset magnitude, the benchmark cells' included.  It
+scores the trailing partial stream on device via the score-neutral
+padded row (``TraceBatch.padded_stream_matrix``).
 """
 
-import jax
+import json
+import os
+import sys
+from pathlib import Path
+
 import numpy as np
 import pytest
 
 from repro.core import TraceBatch, compute_stream_scores, ior, mixed, relabel
 from repro.core.workloads import MiB
+
+ROOT = Path(__file__).resolve().parents[1]
+sys.path.insert(0, os.fspath(ROOT))
+
+from chipbench import ior as cell_ior  # noqa: E402
 
 STREAM_LEN = 128
 
@@ -38,11 +43,7 @@ def _nontrivial_batch(tail: int = 0) -> TraceBatch:
     items = list(mixed(*apps, burst_requests=64).trace)
     if tail:
         items = items[:-tail]
-    batch = TraceBatch.from_items(items)
-    # keep offsets inside the pallas kernel's int32 lanes (the refusal
-    # beyond them is tested separately)
-    assert int(batch.offsets.max()) < np.iinfo(np.int32).max
-    return batch
+    return TraceBatch.from_items(items)
 
 
 @pytest.fixture(scope="module")
@@ -56,11 +57,7 @@ def ragged_batch():
 
 
 def _score(batch, backend):
-    """Score on ``backend``; the kernel runs in the Pallas interpreter
-    because these tests run on the CPU."""
-
-    return compute_stream_scores(batch, STREAM_LEN, backend=backend,
-                                 interpret=backend == "pallas")
+    return compute_stream_scores(batch, STREAM_LEN, backend=backend)
 
 
 def _assert_parity(batch, backend):
@@ -77,18 +74,18 @@ def _assert_parity(batch, backend):
             err_msg=f"{backend}: {field} diverged from numpy oracle")
 
 
-@pytest.mark.parametrize("backend", ["jnp", "pallas"])
+@pytest.mark.parametrize("backend", ["jnp"])
 def test_backend_matches_oracle(batch, backend):
     _assert_parity(batch, backend)
 
 
-@pytest.mark.parametrize("backend", ["jnp", "pallas"])
+@pytest.mark.parametrize("backend", ["jnp"])
 def test_backend_matches_oracle_ragged_tail(ragged_batch, backend):
     _assert_parity(ragged_batch, backend)
 
 
 def test_padded_tail_is_score_neutral(ragged_batch):
-    """The padded row the device backends score must carry the tail's exact
+    """The padded row the device backend scores must carry the tail's exact
     statistics: same rf/dist as the unpadded host scoring of the tail."""
 
     offs_p, szs_p, lens = ragged_batch.padded_stream_matrix(STREAM_LEN)
@@ -110,71 +107,76 @@ def test_padded_tail_is_score_neutral(ragged_batch):
     assert dist_pad[0] == dist_true[0]
 
 
-def _huge_offset_batch() -> TraceBatch:
-    offs = np.array([2**33, 2**33 + 4096, 2**34, 5, 2**31], dtype=np.int64)
+def _batch(columns, n: int) -> TraceBatch:
     return TraceBatch(
-        offsets=offs,
-        sizes=np.full(offs.size, 4096, dtype=np.int64),
-        file_ids=np.zeros(offs.size, dtype=np.int64),
-        app_ids=np.zeros(offs.size, dtype=np.int64),
-        times=np.zeros(offs.size, dtype=np.float64),
+        **columns,
+        times=np.zeros(n, dtype=np.float64),
         gap_positions=np.zeros(0, dtype=np.int64),
         gap_seconds=np.zeros(0, dtype=np.float64),
     )
 
 
-@pytest.mark.parametrize("backend", ["jnp", "pallas"])
-def test_huge_offsets_stay_exact(backend):
-    """Offsets beyond int32: jnp's x64 lanes handle them natively; pallas
-    must refuse them — naming the exact device backend — rather than
-    truncate into wrong seek counts or score them elsewhere."""
+def _huge_offset_batch() -> TraceBatch:
+    offs = np.array([2**33, 2**33 + 4096, 2**34, 5, 2**31], dtype=np.int64)
+    return _batch({"offsets": offs,
+                   "sizes": np.full(offs.size, 4096, dtype=np.int64),
+                   "file_ids": np.zeros(offs.size, dtype=np.int64),
+                   "app_ids": np.zeros(offs.size, dtype=np.int64)}, offs.size)
 
-    batch = _huge_offset_batch()
-    if backend == "pallas":
-        with pytest.raises(ValueError, match="backend='jnp'"):
-            _score(batch, backend)
-        return
+
+def _cell_batch(config: str, requests: int = 4 * STREAM_LEN + 37) -> TraceBatch:
+    """``requests`` consecutive arrivals of a benchmark configuration's
+    first job, up to the one at the layout's largest offset: the cell's
+    own offsets at its full scale (``(ranks * segments - 1) * transfer``,
+    about 2.2e12 B file per process and 4.9e10 B in one shared file)."""
+
+    cfg = json.loads((ROOT / "chipbench" / "configs" / f"{config}.json").read_text())
+    t = cell_ior.ior_trace(cfg, 0, 0)
+    end = int(np.argmax(t["offsets"])) + 1
+    start = max(end - requests, 0)
+    batch = _batch({k: t[k][start:end] for k in ("offsets", "sizes", "file_ids", "app_ids")},
+                   end - start)
+    top = (int(cfg["ranks"]) * int(cfg["segments"]) - 1) * int(cfg["transfer_bytes"])
+    assert int(batch.offsets.max()) == top > np.iinfo(np.int32).max
+    return batch
+
+
+# the ids name the device backend, then the trace
+@pytest.mark.parametrize("make", [
+    pytest.param(_huge_offset_batch, id="jnp"),
+    pytest.param(lambda: _cell_batch("ior_easy_ssdup"), id="jnp-ior_easy_ssdup"),
+    pytest.param(lambda: _cell_batch("ior_hard_ssdup"), id="jnp-ior_hard_ssdup"),
+])
+def test_huge_offsets_stay_exact(make):
+    """Offsets beyond int32 — up to the benchmark cells' own — stay exact
+    on jnp's x64 lanes."""
+
+    batch = make()
     oracle = compute_stream_scores(batch, STREAM_LEN, backend="numpy")
-    scores = _score(batch, backend)
+    scores = _score(batch, "jnp")
     np.testing.assert_array_equal(scores.rf_sum, oracle.rf_sum)
     np.testing.assert_array_equal(scores.percentage, oracle.percentage)
     np.testing.assert_array_equal(scores.seek_distance, oracle.seek_distance)
 
 
-@pytest.mark.parametrize("backend,interpret,ran", [
-    ("numpy", False, "numpy"),
-    ("jnp", False, "jnp"),
-    ("pallas", True, "pallas-interpret"),
-])
-def test_scores_name_the_path_that_ran(batch, backend, interpret, ran):
-    scores = compute_stream_scores(batch, STREAM_LEN, backend=backend,
-                                   interpret=interpret)
-    assert scores.backend == ran
-
-
-def test_compiled_pallas_never_falls_back_off_tpu(batch):
-    """Without a TPU the compiled kernel cannot run; the call must fail
-    instead of quietly interpreting or scoring on the host."""
-
-    if jax.default_backend() == "tpu":
-        pytest.skip("a TPU is attached: the compiled kernel runs here")
-    with pytest.raises(ValueError, match="interpret"):
-        compute_stream_scores(batch, STREAM_LEN, backend="pallas")
+@pytest.mark.parametrize("backend", ["numpy", "jnp"])
+def test_scores_name_the_path_that_ran(batch, backend):
+    scores = compute_stream_scores(batch, STREAM_LEN, backend=backend)
+    assert scores.backend == backend
 
 
 def test_routing_decisions_identical_across_backends(batch):
-    """End-to-end: percentages from the device backends must induce the
+    """End-to-end: percentages from the device backend must induce the
     same redirector decisions as the oracle (fp noise must stay far from
     any threshold boundary on this trace)."""
 
     from repro.core import IONodeSimulator
 
     results = {}
-    for backend in ("numpy", "jnp", "pallas"):
+    for backend in ("numpy", "jnp"):
         scores = _score(batch, backend)
         sim = IONodeSimulator(scheme="ssdup+",
                               ssd_capacity=batch.total_bytes // 2)
         r = sim.run(batch, scores=scores)
         results[backend] = (r.bytes_to_ssd, r.bytes_to_hdd_direct, r.flushes)
     assert results["jnp"] == results["numpy"]
-    assert results["pallas"] == results["numpy"]

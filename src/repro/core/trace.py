@@ -222,17 +222,48 @@ class TraceBatch:                               # __eq__ would raise
         )
 
     def shard(self, assignment: np.ndarray, num_nodes: int) -> list["TraceBatch"]:
-        """Split by a per-request node assignment into ``num_nodes`` batches."""
+        """Split by a per-request node assignment into ``num_nodes`` batches.
+
+        One stable sort by node partitions the trace: every column is
+        gathered once into node-major order, and node ``n``'s batch is the
+        contiguous slice of its requests in arrival order, equal array by
+        array to ``select(np.nonzero(assignment == n)[0])``.  The batches
+        share one read-only buffer per column, so an in-place write to a
+        batch raises instead of changing its neighbour.
+        """
 
         assignment = np.asarray(assignment)
         if assignment.shape[0] != self.num_requests:
             raise ValueError("assignment length != num_requests")
         if assignment.size and (assignment.min() < 0 or assignment.max() >= num_nodes):
             raise ValueError("node assignment out of range")
-        return [
-            self.select(np.nonzero(assignment == node)[0])
-            for node in range(num_nodes)
-        ]
+        # the narrowest unsigned key sends numpy's stable sort down its
+        # radix path
+        key_dtype = (
+            np.uint8 if num_nodes <= 1 << 8
+            else np.uint16 if num_nodes <= 1 << 16
+            else np.int64
+        )
+        key = assignment.astype(key_dtype, copy=False)
+        order = np.argsort(key, kind="stable")
+        cuts = np.zeros(num_nodes + 1, dtype=np.int64)
+        np.cumsum(np.bincount(key, minlength=num_nodes), out=cuts[1:])
+        columns: dict[str, np.ndarray] = {}
+        for name in ("offsets", "sizes", "file_ids", "app_ids", "times"):
+            column = np.take(getattr(self, name), order)
+            column.flags.writeable = False
+            columns[name] = column
+        shards = []
+        for a, b in zip(cuts[:-1].tolist(), cuts[1:].tolist()):
+            shards.append(TraceBatch(
+                **{name: column[a:b] for name, column in columns.items()},
+                # local position = how many of the node's requests precede
+                # the gap; every gap is replicated into every batch
+                gap_positions=np.searchsorted(
+                    order[a:b], self.gap_positions, side="left"),
+                gap_seconds=self.gap_seconds.copy(),
+            ))
+        return shards
 
     # -- stream view ----------------------------------------------------
     def stream_bounds(self, stream_len: int = DEFAULT_STREAM_LEN) -> np.ndarray:

@@ -71,6 +71,99 @@ class TestTraceBatchRoundTrip:
         assert sum(s.total_bytes for s in shards) == batch.total_bytes
 
 
+COLUMNS = ("offsets", "sizes", "file_ids", "app_ids", "times",
+           "gap_positions", "gap_seconds")
+
+
+def gapped_batch(n, gap_positions, seed=0):
+    rng = np.random.default_rng(seed)
+    return TraceBatch(
+        offsets=rng.integers(0, 1 << 40, n, dtype=np.int64),
+        sizes=rng.integers(1, 1 << 21, n, dtype=np.int64),
+        file_ids=rng.integers(0, 160, n, dtype=np.int64),
+        app_ids=rng.integers(0, 4, n, dtype=np.int64),
+        times=np.sort(rng.random(n)),
+        gap_positions=np.asarray(gap_positions, dtype=np.int64),
+        gap_seconds=rng.random(len(gap_positions)),
+    )
+
+
+def shard_case(kind):
+    """``(batch, assignment, num_nodes)`` for one partition case."""
+
+    rng = np.random.default_rng(11)
+    n = 2000
+    gaps = [0, 0, 700, 700, 700, 1333, n, n]  # ends, middle, shared positions
+    if kind == "empty_trace":
+        return gapped_batch(0, [0, 0]), np.zeros(0, np.int64), 4
+    if kind.startswith("random_"):
+        nodes = int(kind.split("_")[1])
+        return gapped_batch(n, gaps), rng.integers(0, nodes, n), nodes
+    if kind == "one_node_idle":
+        a = rng.integers(0, 8, n)
+        a[a == 5] = 6
+        return gapped_batch(n, gaps), a, 8
+    if kind == "changes_every_request":
+        a = np.arange(n) % 64
+        assert np.all(np.diff(a) != 0)
+        return gapped_batch(n, gaps), a, 64
+    if kind == "long_runs":
+        return gapped_batch(n, gaps), np.repeat(rng.integers(0, 64, 8), n // 8), 64
+    raise KeyError(kind)
+
+
+class TestShardPartition:
+    """``TraceBatch.shard`` is the definition it replaced: per node, the
+    requests at ``np.nonzero(assignment == node)`` in arrival order, with
+    every gap replicated and placed as ``select`` places it."""
+
+    @pytest.mark.parametrize("kind", [
+        "random_1", "random_3", "random_64", "random_300", "random_70000",
+        "one_node_idle", "empty_trace", "changes_every_request", "long_runs",
+    ])
+    def test_shard_equals_per_node_select(self, kind):
+        batch, assignment, num_nodes = shard_case(kind)
+        shards = batch.shard(assignment, num_nodes)
+        assert len(shards) == num_nodes
+        present = set(np.unique(assignment).tolist())
+        nobody = batch.select(np.zeros(0, np.int64))  # every absent node's
+        for node, shard in enumerate(shards):
+            want = (
+                batch.select(np.nonzero(assignment == node)[0])
+                if node in present else nobody
+            )
+            for name in COLUMNS:
+                got, ref = getattr(shard, name), getattr(want, name)
+                assert got.dtype == ref.dtype, (node, name)
+                assert np.array_equal(got, ref), (node, name)
+        if kind == "one_node_idle":
+            idle = shards[5]
+            assert idle.num_requests == 0
+            assert idle.num_gaps == batch.num_gaps
+            assert not np.any(idle.gap_positions)
+
+    def test_shard_columns_are_read_only(self):
+        batch, assignment, num_nodes = shard_case("changes_every_request")
+        shards = batch.shard(assignment, num_nodes)
+        before = shards[1].offsets.copy()
+        for name in COLUMNS[:5]:
+            with pytest.raises(ValueError, match="read-only"):
+                getattr(shards[0], name)[-1] = 0
+        with pytest.raises(ValueError, match="read-only"):
+            shards[0].sizes += 1
+        np.testing.assert_array_equal(shards[1].offsets, before)
+        # the gaps are each shard's own
+        shards[0].gap_seconds[0] = -1.0
+        assert shards[1].gap_seconds[0] == batch.gap_seconds[0] >= 0.0
+
+    @pytest.mark.parametrize("assignment", [[0, 1, 3], [-1, 0, 1], [0, 1]])
+    def test_shard_rejects_bad_assignment(self, assignment):
+        batch = gapped_batch(3, [1])
+        match = "length" if len(assignment) != 3 else "out of range"
+        with pytest.raises(ValueError, match=match):
+            batch.shard(np.asarray(assignment), 3)
+
+
 class TestBatchedScoresMatchScalar:
     @pytest.mark.parametrize("stream_len", [32, 128])
     @pytest.mark.parametrize("seed", [0, 1, 2])
